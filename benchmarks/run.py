@@ -33,6 +33,8 @@ def _blockrank():
 
 
 def main() -> None:
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (bench_comm, bench_goffish_vs_vertex,
                             bench_incremental, bench_loading, bench_obs,
                             bench_serving, bench_straggler, bench_supersteps)

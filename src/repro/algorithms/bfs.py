@@ -1,8 +1,6 @@
 """BFS levels = SSSP over unit weights (paper §5.4 traversal class)."""
 from __future__ import annotations
 
-from typing import Optional
-
 import numpy as np
 
 from repro.core import GopherEngine, SemiringProgram, make_bfs_init
@@ -11,7 +9,7 @@ from repro.gofs.formats import PartitionedGraph
 
 def bfs(pg: PartitionedGraph, source_global: int, mode: str = "subgraph",
         backend: str = "local", mesh=None,
-        spmv_backend: Optional[str] = None):
+        spmv_backend: str = "jnp"):
     """Returns (levels (P, v_max) float32 — hop counts, inf unreachable, Telemetry).
     Requires the graph to have been built with unit weights."""
     sp_ = int(pg.part_of[source_global])

@@ -16,7 +16,7 @@ from repro.gofs.formats import PartitionedGraph
 
 def connected_components(pg: PartitionedGraph, mode: str = "subgraph",
                          backend: str = "local", mesh=None,
-                         spmv_backend: Optional[str] = None,
+                         spmv_backend: str = "jnp",
                          max_local_iters: Optional[int] = None):
     """Returns (labels (P, v_max) int64 — component id = max global vertex id
     in the component, -1 on pad slots —, num_components, Telemetry)."""

@@ -92,7 +92,7 @@ def _boundary_sources(pg: PartitionedGraph, reset: np.ndarray) -> np.ndarray:
 def _incremental_run(pg: PartitionedGraph, semiring: str, prev_x: np.ndarray,
                      delta: DeltaResult, init_values: np.ndarray,
                      backend: str = "local", mesh=None,
-                     spmv_backend: Optional[str] = None,
+                     spmv_backend: str = "jnp",
                      max_local_iters: Optional[int] = None,
                      gb: Optional[dict] = None, exchange: str = "auto",
                      tier_plan=None):
@@ -118,7 +118,7 @@ def _incremental_run(pg: PartitionedGraph, semiring: str, prev_x: np.ndarray,
 def incremental_sssp(pg: PartitionedGraph, source_global: int,
                      prev_dist: np.ndarray, delta: DeltaResult,
                      backend: str = "local", mesh=None,
-                     spmv_backend: Optional[str] = None,
+                     spmv_backend: str = "jnp",
                      gb: Optional[dict] = None, exchange: str = "auto",
                      tier_plan=None):
     """SSSP on graph version k+1 from version k's distances. Returns
@@ -139,7 +139,7 @@ def incremental_sssp(pg: PartitionedGraph, source_global: int,
 def incremental_bfs(pg: PartitionedGraph, source_global: int,
                     prev_levels: np.ndarray, delta: DeltaResult,
                     backend: str = "local", mesh=None,
-                    spmv_backend: Optional[str] = None,
+                    spmv_backend: str = "jnp",
                     gb: Optional[dict] = None, exchange: str = "auto",
                     tier_plan=None):
     """BFS = SSSP over unit weights (graph must carry unit weights)."""
@@ -199,7 +199,7 @@ def incremental_sssp_batched(pg: PartitionedGraph, sources_global,
 def incremental_connected_components(
         pg: PartitionedGraph, prev_labels: np.ndarray, delta: DeltaResult,
         backend: str = "local", mesh=None,
-        spmv_backend: Optional[str] = None,
+        spmv_backend: str = "jnp",
         gb: Optional[dict] = None, exchange: str = "auto",
         tier_plan=None) -> Tuple[np.ndarray, int, object]:
     """HCC labels on graph version k+1 from version k's labels. Returns

@@ -1,8 +1,6 @@
 """Max Vertex (paper Algorithm 2) — the didactic example of the abstraction."""
 from __future__ import annotations
 
-from typing import Optional
-
 import numpy as np
 
 from repro.core import GopherEngine, SemiringProgram, init_max_vertex
@@ -11,7 +9,7 @@ from repro.gofs.formats import PartitionedGraph
 
 def max_vertex(pg: PartitionedGraph, mode: str = "subgraph",
                backend: str = "local", mesh=None,
-               spmv_backend: Optional[str] = None):
+               spmv_backend: str = "jnp"):
     """Returns (per-vertex max-reachable-value (P, v_max), Telemetry).
 
     mode='subgraph' -> Gopher (local fixpoint); mode='vertex' -> Giraph-like

@@ -28,7 +28,7 @@ from repro.kernels import ops
 
 def pagerank(pg: PartitionedGraph, num_iters: int = 30, damping: float = 0.85,
              tol: Optional[float] = None, backend: str = "local", mesh=None,
-             spmv_backend: Optional[str] = None, init_r: Optional[np.ndarray] = None):
+             spmv_backend: str = "jnp", init_r: Optional[np.ndarray] = None):
     """Returns (ranks (P, v_max) float32, Telemetry)."""
     init_fn = None
     if init_r is not None:
@@ -49,7 +49,7 @@ def pagerank(pg: PartitionedGraph, num_iters: int = 30, damping: float = 0.85,
 
 
 def _local_pagerank(pg: PartitionedGraph, num_iters: int = 30,
-                    damping: float = 0.85, spmv_backend: Optional[str] = None):
+                    damping: float = 0.85, spmv_backend: str = "jnp"):
     """Phase 1: PageRank of each sub-graph in isolation (local edges only,
     per-sub-graph normalization). Pure local fixpoint — zero messages."""
     nbr = jnp.asarray(pg.nbr)
@@ -92,7 +92,7 @@ def _local_pagerank(pg: PartitionedGraph, num_iters: int = 30,
 def blockrank(pg: PartitionedGraph, damping: float = 0.85,
               tol: float = 1e-7, max_iters: int = 30,
               local_iters: int = 20, backend: str = "local", mesh=None,
-              spmv_backend: Optional[str] = None):
+              spmv_backend: str = "jnp"):
     """Returns (ranks, Telemetry-of-phase-3, info dict)."""
     # phase 1: local per-block PageRank
     local_r = _local_pagerank(pg, num_iters=local_iters, damping=damping,

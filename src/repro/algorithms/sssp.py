@@ -18,7 +18,7 @@ from repro.gofs.formats import PartitionedGraph
 
 def sssp(pg: PartitionedGraph, source_global: int, mode: str = "subgraph",
          backend: str = "local", mesh=None,
-         spmv_backend: Optional[str] = None,
+         spmv_backend: str = "jnp",
          max_local_iters: Optional[int] = None):
     """Returns (distances (P, v_max) float32, inf = unreachable, Telemetry)."""
     sp_ = int(pg.part_of[source_global])

@@ -202,10 +202,12 @@ def _decode_feeds(host_gb: dict):
     return dec(host_gb["ib_lo"]), dec(host_gb["ib_hub"])
 
 
-def device_block(host_gb: dict) -> dict:
+def device_block(host_gb: dict, sharding=None) -> dict:
     """Upload a host block to device (jnp) arrays, decoding the feed maps
     to runtime flat indices (see _SLOT_STRIDE). Host-only metadata
-    (_HOST_ONLY) stays behind."""
+    (_HOST_ONLY) stays behind. ``sharding`` (e.g. a NamedSharding over the
+    partition axis) places every entry straight from the host; None puts
+    the block on the default device."""
     ib_lo, ib_hub = _decode_feeds(host_gb)
     out = {}
     for k, v in host_gb.items():
@@ -215,7 +217,8 @@ def device_block(host_gb: dict) -> dict:
             v = ib_lo
         elif k == "ib_hub":
             v = ib_hub
-        out[k] = jnp.asarray(v)
+        out[k] = (jnp.asarray(v) if sharding is None
+                  else jax.device_put(v, sharding))
     return out
 
 

@@ -1,39 +1,27 @@
-"""Version-spanning wrappers for the handful of jax APIs that moved.
+"""The few jax mesh/shard_map calls the engine makes, in one place.
 
-The repo targets the current jax surface (``jax.shard_map``,
-``jax.make_mesh(axis_types=...)``); the pinned toolchain in some containers
-ships 0.4.x where shard_map lives in ``jax.experimental.shard_map`` (with
-``check_rep`` instead of ``check_vma``) and ``make_mesh`` takes no
-``axis_types``. Everything engine/launch-side goes through these two helpers
-so the BSP core has exactly one place that knows about the skew.
+Everything engine/launch-side builds meshes and shard_maps through these
+helpers so the BSP core has exactly one place that knows the jax surface.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # modern surface
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # jax < 0.4.38
-    _AxisType = None
+from jax.sharding import AxisType
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax version.
+    """``jax.shard_map`` with replication checking off.
 
     The mailbox all_to_all produces per-device blocks whose replication the
     checker cannot infer (same reason the upstream code passes
     ``check_vma=False``), so the check is always disabled.
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(shape, axes, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the kwarg exists."""
+    """``jax.make_mesh`` over the first ``prod(shape)`` devices, Auto axes."""
     shape = tuple(shape)
     axes = tuple(axes)
     if devices is None:
@@ -41,7 +29,11 @@ def make_mesh(shape, axes, devices=None):
         for s in shape:
             n *= s
         devices = jax.devices()[:n]
-    if _AxisType is not None:
-        return jax.make_mesh(shape, axes, devices=devices,
-                             axis_types=(_AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(shape))
+
+
+def abstract_mesh(shape, axes):
+    """A device-free mesh of the given axis sizes and names, for tracing
+    and lowering shard_map loops without the devices (static analysis)."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))

@@ -58,7 +58,8 @@ from jax.sharding import PartitionSpec as P
 from repro.core import compat
 from repro.core import messages as msg
 from repro.resilience import faults as _faults
-from repro.core.blocks import graph_block  # noqa: F401 (re-exported API)
+from repro.core.blocks import (device_block, graph_block,  # noqa: F401
+                               host_graph_block)
 from repro.core.tiers import DEMOTE_STREAK, PhasedTierPlan, TierPlan
 from repro.gofs.formats import PartitionedGraph
 from repro.kernels import megastep as mega
@@ -289,7 +290,13 @@ class GopherEngine:
         served by this engine shares it (and the jit cache entries keyed on
         its shapes)."""
         if self._gb is None:
-            self._gb = graph_block(self.pg)
+            sharding = None
+            if (self.backend == "shard_map"
+                    and isinstance(self.mesh, jax.sharding.Mesh)):
+                # each device holds its own partitions' rows, placed once
+                sharding = jax.sharding.NamedSharding(self.mesh,
+                                                      P(self.axis_name))
+            self._gb = device_block(host_graph_block(self.pg), sharding)
         return self._gb
 
     def _gb_for_run(self, gb):
@@ -308,12 +315,11 @@ class GopherEngine:
             return gb
         if self._mega_cm is None:
             kind = self.program.megastep_kind
-            cm = mega.compose_mailbox(
+            cm = mega.compose_mailbox_arrays(
                 self._graph_block(),
                 adjacency="binned" if kind == "batched_semiring" else "full")
             self._mega_cm = {**self._graph_block(),
-                             **{"mcm_" + k: v for k, v in cm.items()
-                                if k not in mega.MAILBOX_STATICS}}
+                             **{"mcm_" + k: v for k, v in cm.items()}}
         if gb is self._gb:
             return self._mega_cm
         return {**self._mega_cm,
@@ -859,8 +865,9 @@ class GopherEngine:
 
         def bsp_body(c):
             x, ch, fr, step, _, tele = c
-            x2, ch2, fl, li = mega.megastep_semiring(x, ch, fr, cm, semiring,
-                                                     unroll=unroll)
+            x2, ch2, fl, li = mega.megastep_semiring(
+                x, ch, fr, cm, semiring, unroll=unroll,
+                backend=prog.spmv_backend, interpret=prog.interpret)
             tele, nch = sem_fold(tele, step, ch2, li)
             return x2, ch2, fl, step + 1, nch == 0, tele
 
@@ -884,13 +891,13 @@ class GopherEngine:
                     return (~done) & (step < _enter)
 
                 carry = jax.lax.while_loop(pre_cond, bsp_body, carry)
-            if mega._default_backend() == "pallas":
+            if prog.spmv_backend == "pallas":
                 # one multi-superstep launch, mailbox on chip; telemetry is
                 # coarse for these rounds (totals, no per-round histograms)
                 x, ch, fr, step, done, tele = carry
                 x2, ch2, fr2, it, li = mega.resident_megastep_pallas(
                     x, ch, fr, cm, semiring, max_steps=max_s - enter,
-                    interpret=jax.default_backend() != "tpu")
+                    interpret=prog.interpret)
                 pairs, nsent = mega.round_stats(ch2, cm)
                 tele = dict(tele, liters=tele["liters"] + li,
                             sent=tele["sent"] + nsent,
@@ -1506,10 +1513,6 @@ class GopherEngine:
 
         adj = "binned" if kind == "batched_semiring" else "full"
 
-        def prep_fn(gb):
-            cm = mega.compose_mailbox(gb, adjacency=adj)
-            return {k: v for k, v in cm.items() if k not in statics}
-
         if kind == "pagerank":
             def init_fn(gb, cma):
                 cm = with_statics(cma)
@@ -1541,7 +1544,9 @@ class GopherEngine:
             unroll = prog.fixpoint_unroll
             batched = kind == "batched_semiring"
             mk = (mega.megastep_semiring_batched if batched
-                  else mega.megastep_semiring)
+                  else functools.partial(mega.megastep_semiring,
+                                         backend=prog.spmv_backend,
+                                         interpret=prog.interpret))
             tail = (Q,) if batched else ()
 
             def init_fn(gb, cma):
@@ -1567,7 +1572,9 @@ class GopherEngine:
                         for k, v in zip(("x", "changed_v", "frontier"),
                                         flat)}
 
-        fns = dict(prep=jax.jit(prep_fn), init=jax.jit(init_fn),
+        fns = dict(prep=functools.partial(mega.compose_mailbox_arrays,
+                                          adjacency=adj),
+                   init=jax.jit(init_fn),
                    step=jax.jit(step_fn), finish=finish)
         cache[key] = fns
         return fns

@@ -194,7 +194,7 @@ def active_slots(send_mask: jnp.ndarray, ob_inv: jnp.ndarray,
 
 def build_outbox_compact(vals: jnp.ndarray, send_mask: jnp.ndarray,
                          ob_inv: jnp.ndarray, num_parts: int, cap: int,
-                         combine: str, backend=None):
+                         combine: str):
     """Frontier-compacted outbox for ONE source partition. Returns
     (pvals (num_parts, cap), pinv (num_parts, cap) int32,
     counts (num_parts,) int32): per destination row, the packed prefix of
@@ -213,13 +213,13 @@ def build_outbox_compact(vals: jnp.ndarray, send_mask: jnp.ndarray,
     active = active_slots(send_mask, ob_inv, num_parts, cap)
     full = jnp.full((num_parts,), cap, jnp.int32)
     pvals, _, pinv, counts, _ = ops.outbox_pack(slot_vals, active, full,
-                                                ident, backend=backend)
+                                                ident)
     return pvals, pinv, counts
 
 
 def build_outbox_compact_batched(vals: jnp.ndarray, send_mask: jnp.ndarray,
                                  ob_inv: jnp.ndarray, num_parts: int,
-                                 cap: int, combine: str, backend=None):
+                                 cap: int, combine: str):
     """Q-query compacted outbox, QUERY-TRAILING: vals/send are (r_max, Q);
     plan fused into the pack as in build_outbox_compact. Returns
     (pvals (num_parts, cap*Q), pinv (num_parts, cap), counts (num_parts,))."""
@@ -232,7 +232,7 @@ def build_outbox_compact_batched(vals: jnp.ndarray, send_mask: jnp.ndarray,
     active = active_slots(send_mask, ob_inv, num_parts, cap)
     full = jnp.full((num_parts,), cap, jnp.int32)
     pvals, _, pinv, counts, _ = ops.outbox_pack(slot_vals, active, full,
-                                                ident, backend=backend)
+                                                ident)
     return pvals.reshape(num_parts, cap * Q), pinv, counts
 
 

@@ -55,7 +55,8 @@ class SemiringProgram:
     semiring: str                       # min_plus | max_first
     init_fn: Optional[Callable] = None  # gb -> x0 (v_max,); unused when resume
     max_local_iters: Optional[int] = None
-    spmv_backend: Optional[str] = None
+    spmv_backend: str = "jnp"           # "pallas" asks for the Pallas kernels
+    interpret: bool = False             # ... in interpret mode (off the chip)
     fixpoint_unroll: int = 1            # sweeps fused per loop iteration (perf knob)
     resume: bool = False                # start from gb["x0"] / gb["frontier0"]
 
@@ -85,7 +86,8 @@ class SemiringProgram:
 
     def _sweep(self, x, gb):
         y = ops.semiring_spmv(x, gb["nbr"], gb["wgt"], self.semiring,
-                              backend=self.spmv_backend)
+                              backend=self.spmv_backend,
+                              interpret=self.interpret)
         return _ew_combine(self.combine, x, y)
 
     def _masked_sweep(self, x, f, gb):
@@ -93,7 +95,8 @@ class SemiringProgram:
         in-neighbor; the next frontier is the rows that actually changed."""
         y, _ = ops.semiring_spmv_frontier(x, f, gb["nbr"], gb["wgt"],
                                           self.semiring,
-                                          backend=self.spmv_backend)
+                                          backend=self.spmv_backend,
+                                          interpret=self.interpret)
         x2 = _ew_combine(self.combine, x, y)
         return x2, (x2 != x) & gb["vmask"]
 
@@ -167,7 +170,7 @@ class PageRankProgram:
     num_iters: int = 30
     damping: float = 0.85
     tol: Optional[float] = None         # if set, halt early on GLOBAL L1 delta
-    spmv_backend: Optional[str] = None
+    spmv_backend: str = "jnp"           # "pallas" asks for the Pallas kernel
     init_fn: Optional[Callable] = None  # gb -> r0 (BlockRank seeds phase 3 with this)
     teleport_fn: Optional[Callable] = None  # gb -> (v_max,) personalization
                                             # distribution; uniform when None

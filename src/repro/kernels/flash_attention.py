@@ -72,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool,
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            window: Optional[int] = None, q_offset: int = 0,
                            q_block: int = 256, kv_block: int = 256,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """q: (B, Sq, H, dh); k, v: (B, Sk, KV, dh). Returns (B, Sq, H, dh)."""
     B, Sq, H, dh = q.shape
     Sk, KV = k.shape[1], k.shape[2]

@@ -46,7 +46,7 @@ def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def mamba1_scan_pallas(x, delta, Bv, Cv, A, block_d: int = 128,
-                       interpret: bool = True):
+                       interpret: bool = False):
     """y[b,l,d] = Σ_n h[b,l,d,n]·C[b,l,n] with
     h[b,l] = exp(δ[b,l]⊗A)·h[b,l-1] + (δ[b,l]·x[b,l])⊗B[b,l].
 

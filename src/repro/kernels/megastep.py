@@ -23,7 +23,8 @@ This module collapses the whole superstep into ONE dispatch over the flat
   (vs sweep+pack+route = 3+ staged dispatches).
 - :func:`megastep_semiring_pallas` / :func:`resident_megastep_pallas` are
   the Pallas TPU embodiments (``grid=(1,)``, whole problem VMEM-resident,
-  the mailbox an on-chip buffer). The resident kernel runs MULTIPLE
+  the mailbox an on-chip buffer), taken only when a program asks for
+  ``spmv_backend="pallas"``. The resident kernel runs MULTIPLE
   supersteps of a narrow phase inside a single launch, exiting on
   quiescence or the iteration bound — the on-chip-mailbox mode
   :func:`resident_enter_round` gates on the ``PhasedTierPlan`` band
@@ -48,7 +49,7 @@ never launched.
 """
 from __future__ import annotations
 
-from typing import Optional
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -64,10 +65,6 @@ _IDENT = {"min": jnp.inf, "max": -jnp.inf, "sum": 0.0}
 _KIDENT = {"min_plus": jnp.inf, "max_first": -jnp.inf}
 _REDUCE = {"min": jnp.min, "max": jnp.max, "sum": jnp.sum}
 _MAX_IT = 2 ** 30
-
-
-def _default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
 def _ew(combine: str, a, b):
@@ -228,6 +225,15 @@ def compose_mailbox(gb: dict, adjacency: str = "full") -> dict:
     return cm
 
 
+@functools.partial(jax.jit, static_argnames="adjacency")
+def compose_mailbox_arrays(gb: dict, adjacency: str = "full") -> dict:
+    """:func:`compose_mailbox` as one compiled program, returning its device
+    arrays only (``MAILBOX_STATICS`` dropped). Run op by op, the composition
+    is ~120 small programs, each its own XLA compile on a cold cache."""
+    cm = compose_mailbox(gb, adjacency)
+    return {k: v for k, v in cm.items() if k not in MAILBOX_STATICS}
+
+
 # ---------------- fused mailbox delivery ----------------
 
 def deliver_flat(vals, live, cm: dict, combine: str, with_weight: bool):
@@ -339,19 +345,19 @@ def sweep_flat_batched(x, f, cm: dict, semiring: str):
 # ---------------- fused supersteps (jnp oracles + dispatch) ----------------
 
 def megastep_semiring(x, changed, frontier, cm: dict, semiring: str,
-                      unroll: int = 1, backend: Optional[str] = None):
+                      unroll: int = 1, backend: str = "jnp",
+                      interpret: bool = False):
     """One fused superstep for scalar idempotent-semiring programs on flat
     state: deliver the previous round's messages, ⊕-combine, run the
     masked local fixpoint, emit the new send set. Returns
     ``(x2, changed2, f_left, liters)`` with liters per partition matching
     the staged vmapped while_loop's select semantics bit for bit.
-    TPU dispatches the Pallas megakernel; CPU runs the jnp oracle (the
-    kernel is still exercised in interpret mode by the parity tests)."""
-    backend = backend or _default_backend()
+    Runs the jnp oracle unless ``backend="pallas"`` asks for the
+    megakernel (compiled, or interpreted with ``interpret=True``)."""
     if backend == "pallas":
         return megastep_semiring_pallas(
             x, changed, frontier, cm, semiring, unroll=unroll,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret)
     combine = "min" if semiring == "min_plus" else "max"
     vm = cm["vmask"]
     P = cm["num_parts"]

@@ -1,10 +1,11 @@
 """jit'd dispatch wrappers for the kernels.
 
-``semiring_spmv`` picks the execution path:
-- TPU backend      -> Pallas kernel (compiled)
-- CPU (this box)   -> the pure-jnp oracle (same math, XLA-fused); the Pallas
-                      path is still fully exercised in interpret mode by the
-                      kernel tests.
+Every dispatcher runs the pure-jnp route (same math, XLA-fused) unless the
+caller asks for ``backend="pallas"``. The Pallas kernels run only on that
+request, compiled for the chip, or in interpret mode when the caller also
+passes ``interpret=True`` (the kernel parity tests off the chip). Nothing
+here looks at the platform: a requested kernel that the chip's compiler
+refuses raises instead of falling back.
 
 ``multibin_spmv`` is the degree-binned variant for powerlaw graphs (LJ-like):
 rows are bucketed by degree into <=3 ELL bins so padding waste stays bounded;
@@ -12,9 +13,8 @@ results scatter back by row index.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -28,58 +28,51 @@ from repro.kernels.semiring_spmv import (semiring_spmv_frontier_pallas,
                                          semiring_spmv_pallas)
 
 
-def _default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
-
-
 def semiring_spmv(x: jnp.ndarray, nbr: jnp.ndarray, wgt: jnp.ndarray,
-                  semiring: str, backend: Optional[str] = None,
-                  block_v: int = 256) -> jnp.ndarray:
-    backend = backend or _default_backend()
+                  semiring: str, backend: str = "jnp",
+                  block_v: int = 256, interpret: bool = False) -> jnp.ndarray:
     if backend == "jnp":
         return semiring_spmv_ref(x, nbr, wgt, semiring)
     if backend == "pallas":
         return semiring_spmv_pallas(x, nbr, wgt, semiring, block_v=block_v,
-                                    interpret=jax.default_backend() != "tpu")
+                                    interpret=interpret)
     raise ValueError(f"unknown backend {backend}")
 
 
 def semiring_spmv_frontier(x: jnp.ndarray, frontier: jnp.ndarray,
                            nbr: jnp.ndarray, wgt: jnp.ndarray, semiring: str,
-                           backend: Optional[str] = None,
-                           block_v: int = 256):
+                           backend: str = "jnp", block_v: int = 256,
+                           interpret: bool = False):
     """Frontier-masked ELL sweep (idempotent ⊕ only): rows with no active
     in-neighbor yield the identity at ~0 cost (the Pallas path predicates the
     gather+combine per row block on the frontier). Returns (y, row_active)."""
-    backend = backend or _default_backend()
     if backend == "jnp":
         return semiring_spmv_frontier_ref(x, frontier, nbr, wgt, semiring)
     if backend == "pallas":
         return semiring_spmv_frontier_pallas(
             x, frontier, nbr, wgt, semiring, block_v=block_v,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret)
     raise ValueError(f"unknown backend {backend}")
 
 
-def outbox_compact_plan(active: jnp.ndarray, backend: Optional[str] = None,
-                        block_r: int = 8):
+def outbox_compact_plan(active: jnp.ndarray, backend: str = "jnp",
+                        block_r: int = 8, interpret: bool = False):
     """Frontier-compaction plan for the sparse mailbox exchange (Gopher
     Wire): (R, cap) active-slot mask -> (pfwd, pinv, counts). See
     kernels.ref.outbox_compact_plan_ref for the contract; the Pallas path
     is bit-identical (stable ascending order both ways)."""
-    backend = backend or _default_backend()
     if backend == "jnp":
         return outbox_compact_plan_ref(active)
     if backend == "pallas":
-        return outbox_compact_plan_pallas(
-            active, block_r=block_r,
-            interpret=jax.default_backend() != "tpu")
+        return outbox_compact_plan_pallas(active, block_r=block_r,
+                                          interpret=interpret)
     raise ValueError(f"unknown backend {backend}")
 
 
 def outbox_pack(slot_vals: jnp.ndarray, active: jnp.ndarray,
                 limit: jnp.ndarray, ident: float,
-                backend: Optional[str] = None, block_r: int = 8):
+                backend: str = "jnp", block_r: int = 8,
+                interpret: bool = False):
     """Fused compaction plan + value pack + spill detection (Gopher Mesh):
     (R, cap[, Q]) slot values + (R, cap) active mask + (R,) tier budget ->
     (pvals, sids, pinv, counts, over). See kernels.ref.outbox_pack_ref for
@@ -92,19 +85,17 @@ def outbox_pack(slot_vals: jnp.ndarray, active: jnp.ndarray,
     masked scatter the jnp path uses — the per-lane value DMA dominates
     there, not the plan.
     """
-    backend = backend or _default_backend()
     if backend == "jnp":
         return outbox_pack_ref(slot_vals, active, limit, ident)
     if backend == "pallas":
-        interp = jax.default_backend() != "tpu"
         if slot_vals.ndim == 2:
             return outbox_pack_pallas(slot_vals, active, limit, ident,
-                                      block_r=block_r, interpret=interp)
+                                      block_r=block_r, interpret=interpret)
         # Q-batched: plan (+ per-row truncation/overflow) from the fused
         # kernel, Q-vector pack as a masked scatter through pinv
         _, sids, pinv, counts, over = outbox_pack_pallas(
             jnp.zeros(active.shape, jnp.float32), active, limit, ident,
-            block_r=block_r, interpret=interp)
+            block_r=block_r, interpret=interpret)
         r, cap = active.shape
         rows = jnp.arange(r, dtype=jnp.int32)[:, None]
         dest = jnp.where(pinv != PAD, pinv, cap)
@@ -211,7 +202,7 @@ def bin_rows_by_degree(nbr: np.ndarray, wgt: np.ndarray,
 
 
 def multibin_spmv(x: jnp.ndarray, bins: list, v_out: int, semiring: str,
-                  backend: Optional[str] = None) -> jnp.ndarray:
+                  backend: str = "jnp") -> jnp.ndarray:
     """Semiring sweep over degree-binned ELL; scatter bin results to rows."""
     ident = {"min_plus": jnp.inf, "max_first": -jnp.inf, "plus_times": 0.0}[semiring]
     y = jnp.full((v_out,), ident, x.dtype)

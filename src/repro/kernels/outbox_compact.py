@@ -99,7 +99,7 @@ def _pack_kernel(act_ref, val_ref, lim_ref, pvals_ref, sids_ref, pinv_ref,
                    static_argnames=("ident", "block_r", "interpret"))
 def outbox_pack_pallas(slot_vals: jnp.ndarray, active: jnp.ndarray,
                        limit: jnp.ndarray, ident: float, block_r: int = 8,
-                       interpret: bool = True):
+                       interpret: bool = False):
     """(R, cap) slot values + active mask + per-row budget ->
     (pvals, sids, pinv, counts, over); bit-identical to
     kernels.ref.outbox_pack_ref (single-query form)."""
@@ -133,7 +133,7 @@ def outbox_pack_pallas(slot_vals: jnp.ndarray, active: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
 def outbox_compact_plan_pallas(active: jnp.ndarray, block_r: int = 8,
-                               interpret: bool = True):
+                               interpret: bool = False):
     """(R, cap) bool active mask -> (pfwd, pinv, counts); bit-identical to
     kernels.ref.outbox_compact_plan_ref."""
     r, cap = active.shape

@@ -85,7 +85,7 @@ def _spmv_frontier_kernel(x_ref, f_ref, nbr_ref, wgt_ref, y_ref, act_ref, *,
 def semiring_spmv_frontier_pallas(x: jnp.ndarray, frontier: jnp.ndarray,
                                   nbr: jnp.ndarray, wgt: jnp.ndarray,
                                   semiring: str, block_v: int = 256,
-                                  interpret: bool = True):
+                                  interpret: bool = False):
     """Frontier-masked ELL sweep (idempotent semirings only): inactive rows
     return the ⊕-identity without paying the x-gather or the combine.
     Returns (y, row_active); see kernels.ref.semiring_spmv_frontier_ref for
@@ -124,7 +124,7 @@ def semiring_spmv_frontier_pallas(x: jnp.ndarray, frontier: jnp.ndarray,
 @functools.partial(jax.jit, static_argnames=("semiring", "block_v", "interpret"))
 def semiring_spmv_pallas(x: jnp.ndarray, nbr: jnp.ndarray, wgt: jnp.ndarray,
                          semiring: str, block_v: int = 256,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: bool = False) -> jnp.ndarray:
     """y[v] = ⊕_j ( x[nbr[v,j]] ⊗ wgt[v,j] ), Pallas ELL kernel.
 
     x: (V,) f32 — padded so V % block_v == 0 is NOT required (we pad here).

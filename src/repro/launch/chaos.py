@@ -384,6 +384,8 @@ _SCENARIOS = {
 
 
 def main(argv=None) -> int:
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     args = _parse(argv)
     names = [s for s in str(args.scenarios).split(",") if s]
     unknown = [s for s in names if s not in _SCENARIOS]

@@ -91,6 +91,8 @@ def _build(args):
 
 
 def main(argv=None) -> None:
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     args = _parse(argv)
     eng, tracer = _build(args)
     state, tele = eng.run()

@@ -195,12 +195,10 @@ def _hlo_cross_check(entry, eng, summary, violations):
 
 
 def run_matrix(args) -> dict:
-    import jax
-
     from repro.analysis import (check_program, check_semiring, errors,
                                 lint_kernels, verify_collectives)
     from repro.analysis.semiring import REGISTRY
-    from repro.core import GopherEngine
+    from repro.core import GopherEngine, compat
 
     pg = _build_graph(args)
     devices = tuple(int(d) for d in str(args.devices).split(",") if d)
@@ -220,7 +218,7 @@ def run_matrix(args) -> dict:
 
     checked_programs = set()
     for D in devices:
-        mesh = jax.sharding.AbstractMesh((("parts", D),))
+        mesh = compat.abstract_mesh((D,), ("parts",))
         for algo in algos:
             for mode in modes:
                 prog = _program(algo, pg)
@@ -286,7 +284,7 @@ def run_matrix(args) -> dict:
                                 validate_service, validate_stage_fns)
     staged = []
     for D in devices:
-        mesh = jax.sharding.AbstractMesh((("parts", D),))
+        mesh = compat.abstract_mesh((D,), ("parts",))
         eng = GopherEngine(pg, _program("sssp", pg), backend="shard_map",
                            mesh=mesh, exchange="compact")
         entry = {"driver": "staged", "D": D}

@@ -80,25 +80,21 @@ def _pick_block(s: int, pref: int) -> int:
 
 def flash_attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
                     q_offset=0, q_block: int = 512, kv_block: int = 1024,
-                    use_kernel: Optional[bool] = None):
+                    use_kernel: bool = False):
     """Blockwise streaming attention (online softmax) — O(S) memory.
 
     q: (B, Sq, H, dh); k, v: (B, Sk, KV, dh) with H % KV == 0 (GQA).
     ``q_offset`` is the absolute position of q[0] (prefill continuation).
     ``window``: sliding-window size (keys with q_pos - k_pos >= window masked).
 
-    On TPU this dispatches to the fused Pallas kernel
-    (repro.kernels.flash_attention) — the XLA-level loop below streams score
-    tiles through HBM, which the dry-run roofline shows is the dominant
-    memory term for dense-attention training cells.
+    ``use_kernel=True`` dispatches to the fused Pallas kernel
+    (repro.kernels.flash_attention), compiled for the chip; the default is
+    the XLA-level loop below.
     """
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu" and isinstance(q_offset, int)
     if use_kernel:
         from repro.kernels.flash_attention import flash_attention_pallas
         return flash_attention_pallas(
-            q, k, v, causal=causal, window=window, q_offset=q_offset,
-            interpret=jax.default_backend() != "tpu")
+            q, k, v, causal=causal, window=window, q_offset=q_offset)
     B, Sq, H, dh = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     g = H // KV

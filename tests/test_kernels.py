@@ -32,7 +32,8 @@ def test_pallas_matches_ref(semiring, v, d, bv):
     rng = np.random.default_rng(hash((semiring, v, d)) % 2**31)
     x, nbr, wgt = _random_ell(rng, v, d)
     got = semiring_spmv_pallas(jnp.asarray(x), jnp.asarray(nbr),
-                               jnp.asarray(wgt), semiring, block_v=bv)
+                               jnp.asarray(wgt), semiring, block_v=bv,
+                               interpret=True)
     want = semiring_spmv_ref(jnp.asarray(x), jnp.asarray(nbr),
                              jnp.asarray(wgt), semiring)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -47,7 +48,8 @@ def test_all_pad_rows(semiring):
     wgt = np.zeros((v, 8), np.float32)
     x = np.ones(v, np.float32)
     got = np.asarray(semiring_spmv_pallas(
-        jnp.asarray(x), jnp.asarray(nbr), jnp.asarray(wgt), semiring, block_v=8))
+        jnp.asarray(x), jnp.asarray(nbr), jnp.asarray(wgt), semiring, block_v=8,
+        interpret=True))
     ident = {"min_plus": np.inf, "max_first": -np.inf, "plus_times": 0.0}[semiring]
     assert np.all(got == ident)
 
@@ -64,7 +66,8 @@ def test_vmap_over_partitions():
         wgts.append(wgt)
     xs, nbrs, wgts = map(np.stack, (xs, nbrs, wgts))
     got = jax.vmap(lambda a, b, c: semiring_spmv_pallas(a, b, c, "min_plus",
-                                                        block_v=16))(
+                                                        block_v=16,
+                                                        interpret=True))(
         jnp.asarray(xs), jnp.asarray(nbrs), jnp.asarray(wgts))
     for p in range(P):
         want = semiring_spmv_ref(jnp.asarray(xs[p]), jnp.asarray(nbrs[p]),
@@ -103,7 +106,8 @@ def test_property_pallas_equals_ref(v, d, seed, semiring):
     rng = np.random.default_rng(seed)
     x, nbr, wgt = _random_ell(rng, v, d)
     got = semiring_spmv_pallas(jnp.asarray(x), jnp.asarray(nbr),
-                               jnp.asarray(wgt), semiring, block_v=32)
+                               jnp.asarray(wgt), semiring, block_v=32,
+                               interpret=True)
     want = semiring_spmv_ref(jnp.asarray(x), jnp.asarray(nbr),
                              jnp.asarray(wgt), semiring)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -140,7 +144,7 @@ def test_flash_kernel_matches_naive(H, KV, window):
     k = jax.random.normal(jax.random.PRNGKey(1), (B, S, KV, dh))
     v = jax.random.normal(jax.random.PRNGKey(2), (B, S, KV, dh))
     got = flash_attention_pallas(q, k, v, causal=True, window=window,
-                                 q_block=8, kv_block=8)
+                                 q_block=8, kv_block=8, interpret=True)
     want = _naive_attn(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
@@ -153,7 +157,8 @@ def test_flash_kernel_matches_layer_impl():
     q = jax.random.normal(jax.random.PRNGKey(3), (B, S, H, dh))
     k = jax.random.normal(jax.random.PRNGKey(4), (B, S, KV, dh))
     v = jax.random.normal(jax.random.PRNGKey(5), (B, S, KV, dh))
-    got = flash_attention_pallas(q, k, v, q_block=16, kv_block=16)
+    got = flash_attention_pallas(q, k, v, q_block=16, kv_block=16,
+                                 interpret=True)
     want = flash_attention(q, k, v, q_block=16, kv_block=16)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
@@ -171,7 +176,7 @@ def test_mamba_scan_kernel_matches_ref(B, L, D, N, bd):
     Bv = jnp.asarray(rng.standard_normal((B, L, N)), jnp.float32)
     Cv = jnp.asarray(rng.standard_normal((B, L, N)), jnp.float32)
     A = -jnp.asarray(rng.uniform(0.5, 2.0, (D, N)), jnp.float32)
-    got = mamba1_scan_pallas(x, dt, Bv, Cv, A, block_d=bd)
+    got = mamba1_scan_pallas(x, dt, Bv, Cv, A, block_d=bd, interpret=True)
     want = mamba1_scan_ref(x, dt, Bv, Cv, A)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -188,7 +193,7 @@ def test_mamba_scan_kernel_matches_mixer_core():
     Bv = jnp.asarray(rng.standard_normal((B, L, N)), jnp.float32)
     Cv = jnp.asarray(rng.standard_normal((B, L, N)), jnp.float32)
     A = -jnp.asarray(rng.uniform(0.5, 1.5, (D, N)), jnp.float32)
-    got = mamba1_scan_pallas(x, dt, Bv, Cv, A, block_d=8)
+    got = mamba1_scan_pallas(x, dt, Bv, Cv, A, block_d=8, interpret=True)
     want = mamba1_scan_ref(x, dt, Bv, Cv, A)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
                                atol=1e-5)

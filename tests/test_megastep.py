@@ -9,6 +9,8 @@ logical frontier observation (pair_slots / count_hist / messages_sent)
 must match the compact path exactly so the tier-profile EWMAs keep
 learning from fused runs, while wire_slots/bytes_on_wire are zero.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -233,14 +235,14 @@ def test_pallas_megastep_matches_oracle(road):
             x, ch, fr = xo, cho, fo
 
 
-def test_engine_dispatches_pallas_backend(road, monkeypatch):
-    """Force _default_backend to 'pallas' (interpret on CPU) and run the
+def test_engine_dispatches_pallas_backend(road):
+    """Ask for the Pallas backend (interpreted, off the chip) and run the
     whole engine loop through the megakernel embodiment."""
     _, pg = road
     prog = _programs(pg)["cc"]
     s_ref, t_ref = GopherEngine(pg, prog, exchange="dense").run()
-    monkeypatch.setattr(mega, "_default_backend", lambda: "pallas")
-    s, t = GopherEngine(pg, prog, exchange="megastep").run()
+    pallas = dataclasses.replace(prog, spmv_backend="pallas", interpret=True)
+    s, t = GopherEngine(pg, pallas, exchange="megastep").run()
     assert np.array_equal(np.asarray(s["x"]), np.asarray(s_ref["x"]))
     assert t.supersteps == t_ref.supersteps
 

@@ -187,7 +187,7 @@ def test_fused_pack_matches_plan_oracle(backend):
     full = jnp.full((R,), cap, jnp.int32)
     pvals, sids, pinv, counts, over = ops.outbox_pack(
         jnp.asarray(vals), jnp.asarray(act), full, np.inf, backend=backend,
-        block_r=4)
+        block_r=4, interpret=True)
     pfwd_o, pinv_o, counts_o = ops.outbox_compact_plan(jnp.asarray(act),
                                                        backend="jnp")
     assert np.array_equal(np.asarray(pinv), np.asarray(pinv_o))
@@ -210,7 +210,7 @@ def test_fused_pack_truncation_and_overflow(backend):
     lim = jnp.asarray(rng.integers(0, 6, R), jnp.int32)
     pvals, sids, pinv, counts, over = ops.outbox_pack(
         jnp.asarray(vals), jnp.asarray(act), lim, 0.0, backend=backend,
-        block_r=4)
+        block_r=4, interpret=True)
     counts, over = np.asarray(counts), np.asarray(over)
     assert np.array_equal(counts, act.sum(1))    # counts are PRE-truncation
     assert np.array_equal(over, (act.sum(1) > np.asarray(lim)).astype(np.int32))
@@ -233,7 +233,8 @@ def test_fused_pack_batched_matches_single():
     lim = jnp.asarray(rng.integers(1, 5, R), jnp.int32)
     for backend in ("jnp", "pallas"):
         pv, sids, pinv, counts, over = ops.outbox_pack(
-            jnp.asarray(vals), jnp.asarray(act), lim, 0.0, backend=backend)
+            jnp.asarray(vals), jnp.asarray(act), lim, 0.0, backend=backend,
+            interpret=True)
         for q in range(Q):
             pq, sq, iq, cq, oq = ops.outbox_pack(
                 jnp.asarray(vals[:, :, q]), jnp.asarray(act), lim, 0.0,

@@ -392,14 +392,12 @@ def test_phased_multi_device_collectives_static():
     between two genuinely DIFFERENT collective routes (dense all_to_all
     vs tiered all_to_all + ppermute), which is deadlock-free only because
     its predicate is replicated by a full mesh-axis psum."""
-    import jax
-
     from repro.analysis import verify_collectives
     # P=8 over D=4 so the tier schedule has warm (ppermute) lanes, not
     # just the hot all_to_all — same shape as the subprocess smoke below
     g = road_grid(10, 10, drop_frac=0.05, seed=1, weighted=True)
     pg = partition_graph(g, bfs_grow_partition(g, 8, seed=0), 8)
-    mesh = jax.sharding.AbstractMesh((("parts", 4),))
+    mesh = compat.abstract_mesh((4,), ("parts",))
     prog = SemiringProgram(semiring="min_plus",
                            init_fn=make_sssp_init(int(pg.part_of[0]),
                                                   int(pg.local_of[0])))

@@ -60,7 +60,7 @@ def _phased_plan(pg):
 
 @pytest.mark.parametrize("mode", ["dense", "compact", "tiered", "phased"])
 def test_collectives_clean_on_real_engine(pg8, mode):
-    mesh = jax.sharding.AbstractMesh((("parts", 4),))
+    mesh = compat.abstract_mesh((4,), ("parts",))
     prog = SemiringProgram(semiring="max_first", init_fn=init_max_vertex)
     plan = _phased_plan(pg8) if mode == "phased" else None
     eng = GopherEngine(pg8, prog, backend="shard_map", mesh=mesh,
@@ -101,7 +101,7 @@ def test_cond_collective_mismatch_caught():
     """Branches issuing different collectives under a NON-replicated
     predicate (derived from axis_index) is the SPMD deadlock shape — the
     diagnostic must name the cond equation and show both branch traces."""
-    mesh = jax.sharding.AbstractMesh((("parts", 4),))
+    mesh = compat.abstract_mesh((4,), ("parts",))
 
     def body(x):
         i = jax.lax.axis_index("parts")
@@ -132,7 +132,7 @@ def test_cond_collective_mismatch_caught():
 def test_cond_mismatch_allowed_when_predicate_replicated():
     """The phased dense-retry shape: branches differ but the predicate
     rides a full mesh-axis psum — provably uniform, so no violation."""
-    mesh = jax.sharding.AbstractMesh((("parts", 4),))
+    mesh = compat.abstract_mesh((4,), ("parts",))
 
     def body(x):
         flag = jax.lax.psum((x.sum() > 0).astype(jnp.int32), "parts")
@@ -156,7 +156,7 @@ def test_cond_mismatch_allowed_when_predicate_replicated():
 
 
 def test_phased_engine_retry_conds_proven_safe(pg8):
-    mesh = jax.sharding.AbstractMesh((("parts", 4),))
+    mesh = compat.abstract_mesh((4,), ("parts",))
     prog = SemiringProgram(
         semiring="min_plus",
         init_fn=make_sssp_init(int(pg8.part_of[0]), int(pg8.local_of[0])))

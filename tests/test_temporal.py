@@ -250,7 +250,8 @@ def test_frontier_sweep_matches_full_on_active_rows(semiring):
     # pallas interpret path agrees with the jnp oracle
     y_p, act_p = ops.semiring_spmv_frontier(
         jnp.asarray(x), jnp.asarray(frontier), jnp.asarray(nbr),
-        jnp.asarray(wgt), semiring, backend="pallas", block_v=16)
+        jnp.asarray(wgt), semiring, backend="pallas", block_v=16,
+        interpret=True)
     assert np.array_equal(np.asarray(y_p), np.asarray(y_m))
     assert np.array_equal(np.asarray(act_p), act)
 
