@@ -24,28 +24,30 @@ import numpy as np
 from repro.core import GopherEngine, PageRankProgram, meta_graph
 from repro.gofs.formats import PAD, PartitionedGraph
 from repro.kernels import ops
+from repro.obs import step
 
 
 def pagerank(pg: PartitionedGraph, num_iters: int = 30, damping: float = 0.85,
              tol: Optional[float] = None, backend: str = "local", mesh=None,
              spmv_backend: str = "jnp", init_r: Optional[np.ndarray] = None):
     """Returns (ranks (P, v_max) float32, Telemetry)."""
-    init_fn = None
-    if init_r is not None:
-        r0 = jnp.asarray(init_r)
+    with step("entry"):
+        init_fn = None
+        if init_r is not None:
+            r0 = jnp.asarray(init_r)
 
-        def init_fn(gb):  # noqa: E306
-            return r0[gb["part_index"]]
+            def init_fn(gb):  # noqa: E306
+                return r0[gb["part_index"]]
 
-    prog = PageRankProgram(n_global=pg.n_global, num_iters=num_iters,
-                           damping=damping, tol=tol, spmv_backend=spmv_backend,
-                           init_fn=init_fn)
-    eng = GopherEngine(pg, prog, backend=backend, mesh=mesh,
-                       max_supersteps=max(num_iters + 1, 64))
-    state, tele = eng.run()
-    r = np.array(state["r"])
-    r[~pg.vmask] = 0.0
-    return r, tele
+        prog = PageRankProgram(n_global=pg.n_global, num_iters=num_iters,
+                               damping=damping, tol=tol,
+                               spmv_backend=spmv_backend, init_fn=init_fn)
+        eng = GopherEngine(pg, prog, backend=backend, mesh=mesh,
+                           max_supersteps=max(num_iters + 1, 64))
+        state, tele = eng.run()
+        r = np.array(state["r"])
+        r[~pg.vmask] = 0.0
+        return r, tele
 
 
 def _local_pagerank(pg: PartitionedGraph, num_iters: int = 30,

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.core import GopherEngine, SemiringProgram, make_sssp_init
 from repro.gofs.formats import PartitionedGraph
+from repro.obs import step
 
 
 def sssp(pg: PartitionedGraph, source_global: int, mode: str = "subgraph",
@@ -21,14 +22,15 @@ def sssp(pg: PartitionedGraph, source_global: int, mode: str = "subgraph",
          spmv_backend: str = "jnp",
          max_local_iters: Optional[int] = None):
     """Returns (distances (P, v_max) float32, inf = unreachable, Telemetry)."""
-    sp_ = int(pg.part_of[source_global])
-    sl_ = int(pg.local_of[source_global])
-    prog = SemiringProgram(
-        semiring="min_plus", init_fn=make_sssp_init(sp_, sl_),
-        max_local_iters=(max_local_iters if mode == "subgraph" else 1),
-        spmv_backend=spmv_backend)
-    eng = GopherEngine(pg, prog, backend=backend, mesh=mesh)
-    state, tele = eng.run()
-    dist = np.array(state["x"])
-    dist[~pg.vmask] = np.inf
-    return dist, tele
+    with step("entry"):
+        sp_ = int(pg.part_of[source_global])
+        sl_ = int(pg.local_of[source_global])
+        prog = SemiringProgram(
+            semiring="min_plus", init_fn=make_sssp_init(sp_, sl_),
+            max_local_iters=(max_local_iters if mode == "subgraph" else 1),
+            spmv_backend=spmv_backend)
+        eng = GopherEngine(pg, prog, backend=backend, mesh=mesh)
+        state, tele = eng.run()
+        dist = np.array(state["x"])
+        dist[~pg.vmask] = np.inf
+        return dist, tele

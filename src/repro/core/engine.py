@@ -153,6 +153,11 @@ class Telemetry:
     # process can't see real per-partition compute splits). None on the
     # fused single-dispatch loops, which have no per-superstep host clock.
     part_seconds: Optional[np.ndarray] = None  # (P,) float64
+    # the sweeps the megastep's flat fixpoint ran in lockstep over every
+    # partition, summed over supersteps (PageRank: one per superstep) —
+    # what the device executed, where local_iters counts per partition.
+    # None on the staged loops, which carry no lockstep count.
+    lockstep_sweeps: Optional[int] = None
 
     @staticmethod
     def model_bytes(slots: int, num_parts: int, rounds: int, cap: int,
@@ -184,96 +189,97 @@ class GopherEngine:
                  tracer: Optional["obs_trace.Tracer"] = None,
                  metrics: Optional["obs_metrics.MetricsRegistry"] = None,
                  validate: bool = False):
-        assert backend in ("local", "shard_map")
-        assert exchange in ("auto", "compact", "dense", "tiered", "phased",
-                            "megastep")
-        if backend == "shard_map":
-            assert mesh is not None
-            d = mesh.shape[axis_name]
-            assert pg.num_parts % d == 0, "partitions must tile the mesh axis"
-        self.pg = pg
-        self.program = program
-        self.backend = backend
-        self.mesh = mesh
-        self.axis_name = axis_name
-        self.max_supersteps = max_supersteps
-        # wire discipline. 'auto' resolves per backend + program:
-        #   * 'local' + an ELIGIBLE program (program.megastep_kind not None,
-        #     i.e. the sub-graph centric run-to-fixpoint schedule or
-        #     fixed-iteration PageRank) -> 'megastep' (Gopher Hot): there is
-        #     no physical wire to route, so the winning move is to stop
-        #     dispatching the staged sweep/pack/route/halt stages at all and
-        #     fuse the superstep into one launch — this beats even the
-        #     dense single-host transpose at small frontiers (BENCH_comm's
-        #     small-frontier gate holds it to that claim);
-        #   * 'local' with an ineligible program — and a DEGENERATE 1-device
-        #     shard_map mesh, where every partition shares one chip — the
-        #     physical "wire" is a single-device transpose, so the dense
-        #     path is the smallest remaining choice: any compaction plan is
-        #     pure overhead there;
-        #   * a multi-device 'shard_map' mesh -> 'tiered': the routed
-        #     buffers track the frontier.
-        # 'dense' stays the parity / benchmark oracle; 'compact' is Gopher
-        # Wire's protocol-payload compaction over dense physical buffers;
-        # 'phased' (Gopher Phases) is requested explicitly with a
-        # PhasedTierPlan; 'megastep' may also be requested explicitly.
-        self.exchange_requested = exchange
-        if exchange == "auto":
-            if (backend == "local"
-                    and getattr(program, "megastep_kind", None) is not None):
-                exchange = "megastep"
-            else:
-                local_wire = (backend == "local"
-                              or int(mesh.shape[axis_name]) == 1)
-                exchange = "dense" if local_wire else "tiered"
-        self.exchange = exchange
-        if self.exchange == "megastep":
-            assert backend == "local", \
-                "the megastep exchange is a local-backend route (flat state " \
-                "spans every partition; shard_map meshes route tiered/phased)"
-            assert getattr(program, "megastep_kind", None) is not None, \
-                "program is not megastep-eligible (megastep_kind is None)"
-        # plan/mode normalization, both directions: a PhasedTierPlan under
-        # 'tiered' (e.g. a narrow_resume plan handed to exchange='auto' that
-        # resolved tiered) upgrades the mode to 'phased' — a K=1 phased loop
-        # is the tiered exchange plus the per-superstep dense retry — and a
-        # plain TierPlan under 'phased' wraps as a single phase.
-        if self.exchange == "tiered" and isinstance(tier_plan, PhasedTierPlan):
-            self.exchange = "phased"
-        if self.exchange == "tiered" and tier_plan is None:
-            # structural default plan: every pair's width covers its maximum
-            # possible slot count, so it can never overflow (see TierPlan)
-            tier_plan = TierPlan.from_graph(pg)
-        if self.exchange == "phased":
-            if tier_plan is None:
-                tier_plan = PhasedTierPlan.from_graph(pg)
-            elif isinstance(tier_plan, TierPlan):
-                tier_plan = PhasedTierPlan.from_tier_plan(tier_plan)
-        # the megastep route keeps a provided plan too: a PhasedTierPlan's
-        # band geometry gates the resident narrow-phase mode (None = pure
-        # per-superstep fused BSP, still one dispatch per superstep)
-        self.tier_plan = (tier_plan
-                          if self.exchange in ("tiered", "phased", "megastep")
-                          else None)
-        self._gb = gb                # cached device-side graph block; pass a
-                                     # shared one so many engines (a serving
-                                     # fleet) reuse a single device copy
-        self._mega_cm = None         # lazily composed megastep mailbox
-                                     # arrays (see _gb_for_run)
-        self._runner_memo = {}       # per-engine front of _RUNNER_CACHE
-        # Gopher Scope: host-side observability. None defers to the process
-        # defaults at run time (so launch/scope can arm a tracer AFTER
-        # engines were built). A disabled tracer keeps the compiled fused
-        # loop untouched — the traced stepped driver only replaces it when
-        # the tracer is enabled.
-        self._tracer = tracer
-        self._metrics = metrics
-        # Gopher Sentinel: validate=True runs the static passes (SPMD
-        # collective verification + semiring laws + plan staticness, see
-        # repro.analysis) on every compiled-loop cache MISS, before the
-        # loop enters the cache — a cache hit means an identical
-        # configuration already passed, so warm paths pay nothing.
-        self.validate = validate
+        with obs_trace.step("engine", tracer=tracer, metrics=metrics):
+            assert backend in ("local", "shard_map")
+            assert exchange in ("auto", "compact", "dense", "tiered", "phased",
+                                "megastep")
+            if backend == "shard_map":
+                assert mesh is not None
+                d = mesh.shape[axis_name]
+                assert pg.num_parts % d == 0, "partitions must tile the mesh axis"
+            self.pg = pg
+            self.program = program
+            self.backend = backend
+            self.mesh = mesh
+            self.axis_name = axis_name
+            self.max_supersteps = max_supersteps
+            # wire discipline. 'auto' resolves per backend + program:
+            #   * 'local' + an ELIGIBLE program (program.megastep_kind not None,
+            #     i.e. the sub-graph centric run-to-fixpoint schedule or
+            #     fixed-iteration PageRank) -> 'megastep' (Gopher Hot): there is
+            #     no physical wire to route, so the winning move is to stop
+            #     dispatching the staged sweep/pack/route/halt stages at all and
+            #     fuse the superstep into one launch — this beats even the
+            #     dense single-host transpose at small frontiers (BENCH_comm's
+            #     small-frontier gate holds it to that claim);
+            #   * 'local' with an ineligible program — and a DEGENERATE 1-device
+            #     shard_map mesh, where every partition shares one chip — the
+            #     physical "wire" is a single-device transpose, so the dense
+            #     path is the smallest remaining choice: any compaction plan is
+            #     pure overhead there;
+            #   * a multi-device 'shard_map' mesh -> 'tiered': the routed
+            #     buffers track the frontier.
+            # 'dense' stays the parity / benchmark oracle; 'compact' is Gopher
+            # Wire's protocol-payload compaction over dense physical buffers;
+            # 'phased' (Gopher Phases) is requested explicitly with a
+            # PhasedTierPlan; 'megastep' may also be requested explicitly.
+            self.exchange_requested = exchange
+            if exchange == "auto":
+                if (backend == "local"
+                        and getattr(program, "megastep_kind", None) is not None):
+                    exchange = "megastep"
+                else:
+                    local_wire = (backend == "local"
+                                  or int(mesh.shape[axis_name]) == 1)
+                    exchange = "dense" if local_wire else "tiered"
+            self.exchange = exchange
+            if self.exchange == "megastep":
+                assert backend == "local", \
+                    "the megastep exchange is a local-backend route (flat state " \
+                    "spans every partition; shard_map meshes route tiered/phased)"
+                assert getattr(program, "megastep_kind", None) is not None, \
+                    "program is not megastep-eligible (megastep_kind is None)"
+            # plan/mode normalization, both directions: a PhasedTierPlan under
+            # 'tiered' (e.g. a narrow_resume plan handed to exchange='auto' that
+            # resolved tiered) upgrades the mode to 'phased' — a K=1 phased loop
+            # is the tiered exchange plus the per-superstep dense retry — and a
+            # plain TierPlan under 'phased' wraps as a single phase.
+            if self.exchange == "tiered" and isinstance(tier_plan, PhasedTierPlan):
+                self.exchange = "phased"
+            if self.exchange == "tiered" and tier_plan is None:
+                # structural default plan: every pair's width covers its maximum
+                # possible slot count, so it can never overflow (see TierPlan)
+                tier_plan = TierPlan.from_graph(pg)
+            if self.exchange == "phased":
+                if tier_plan is None:
+                    tier_plan = PhasedTierPlan.from_graph(pg)
+                elif isinstance(tier_plan, TierPlan):
+                    tier_plan = PhasedTierPlan.from_tier_plan(tier_plan)
+            # the megastep route keeps a provided plan too: a PhasedTierPlan's
+            # band geometry gates the resident narrow-phase mode (None = pure
+            # per-superstep fused BSP, still one dispatch per superstep)
+            self.tier_plan = (tier_plan
+                              if self.exchange in ("tiered", "phased", "megastep")
+                              else None)
+            self._gb = gb                # cached device-side graph block; pass a
+                                         # shared one so many engines (a serving
+                                         # fleet) reuse a single device copy
+            self._mega_cm = None         # lazily composed megastep mailbox
+                                         # arrays (see _gb_for_run)
+            self._runner_memo = {}       # per-engine front of _RUNNER_CACHE
+            # Gopher Scope: host-side observability. None defers to the process
+            # defaults at run time (so launch/scope can arm a tracer AFTER
+            # engines were built). A disabled tracer keeps the compiled fused
+            # loop untouched — the traced stepped driver only replaces it when
+            # the tracer is enabled.
+            self._tracer = tracer
+            self._metrics = metrics
+            # Gopher Sentinel: validate=True runs the static passes (SPMD
+            # collective verification + semiring laws + plan staticness, see
+            # repro.analysis) on every compiled-loop cache MISS, before the
+            # loop enters the cache — a cache hit means an identical
+            # configuration already passed, so warm paths pay nothing.
+            self.validate = validate
 
     @property
     def tracer(self) -> "obs_trace.Tracer":
@@ -284,6 +290,11 @@ class GopherEngine:
     def metrics(self) -> "obs_metrics.MetricsRegistry":
         return (self._metrics if self._metrics is not None
                 else obs_metrics.default_registry())
+
+    def _step(self, name: str):
+        """A ``gopher.<name>`` program span into this engine's tracer and
+        registry (obs.trace.step)."""
+        return obs_trace.step(name, tracer=self.tracer, metrics=self.metrics)
 
     def _graph_block(self):
         """The device graph block, built once per engine — every query batch
@@ -296,7 +307,10 @@ class GopherEngine:
                 # each device holds its own partitions' rows, placed once
                 sharding = jax.sharding.NamedSharding(self.mesh,
                                                       P(self.axis_name))
-            self._gb = device_block(host_graph_block(self.pg), sharding)
+            with self._step("layout"):
+                host_gb = host_graph_block(self.pg)
+            with self._step("upload"):
+                self._gb = device_block(host_gb, sharding)
         return self._gb
 
     def _gb_for_run(self, gb):
@@ -315,10 +329,12 @@ class GopherEngine:
             return gb
         if self._mega_cm is None:
             kind = self.program.megastep_kind
-            cm = mega.compose_mailbox_arrays(
-                self._graph_block(),
-                adjacency="binned" if kind == "batched_semiring" else "full")
-            self._mega_cm = {**self._graph_block(),
+            block = self._graph_block()
+            with self._step("compose_mailbox"):
+                cm = mega.compose_mailbox_arrays(
+                    block, adjacency=("binned" if kind == "batched_semiring"
+                                      else "full"))
+            self._mega_cm = {**block,
                              **{"mcm_" + k: v for k, v in cm.items()}}
         if gb is self._gb:
             return self._mega_cm
@@ -758,12 +774,14 @@ class GopherEngine:
                 whist=jnp.zeros((max_s + 1,), jnp.int32),
                 chist=jnp.zeros((max_s + 1,), jnp.int32)
                     .at[0].set(jnp.sum(pairs0).astype(jnp.int32)),
-                sent=nsent0, wire=jnp.int32(0), pairs=pairs0)
+                sent=nsent0, wire=jnp.int32(0), pairs=pairs0,
+                lsweeps=jnp.int32(0))
             if Q is not None:
                 tele["qsteps"] = jnp.zeros((Q,), jnp.int32)
             return tele
 
-        def fold(tele, step, pairs, nsent, li, nchanged):
+        @jax.named_scope("gopher.stats")
+        def fold(tele, step, pairs, nsent, li, nchanged, sweeps):
             new = dict(liters=tele["liters"] + li,
                        hist=tele["hist"].at[step].set(nchanged),
                        whist=tele["whist"],
@@ -771,10 +789,13 @@ class GopherEngine:
                            .set(jnp.sum(pairs).astype(jnp.int32)),
                        sent=tele["sent"] + nsent,
                        wire=tele["wire"],
-                       pairs=tele["pairs"] + pairs)
+                       pairs=tele["pairs"] + pairs,
+                       lsweeps=tele["lsweeps"] + sweeps)
             if Q is not None:
                 new["qsteps"] = tele["qsteps"]
             return new
+
+        round_stats = jax.named_scope("gopher.stats")(mega.round_stats)
 
         if kind == "pagerank":
             r = state0["r"].reshape(-1)
@@ -782,7 +803,7 @@ class GopherEngine:
             telep = (jax.vmap(prog.teleport_fn)(gb).reshape(-1)
                      if prog.teleport_fn is not None
                      else 1.0 / prog.n_global)
-            pairs0, nsent0 = mega.round_stats(None, cm)
+            pairs0, nsent0 = round_stats(None, cm)
             tele0 = base_tele(pairs0, nsent0)
 
             def cond(c):
@@ -797,10 +818,11 @@ class GopherEngine:
                 # PageRank sends unconditionally, so every round's logical
                 # observation is the full slot occupancy — including the
                 # final round, matching the staged loop's last exchange
-                pairs, nsent = mega.round_stats(None, cm)
+                pairs, nsent = round_stats(None, cm)
                 nch = chg.astype(jnp.int32) * jnp.int32(p_local)
                 tele = fold(tele, step, pairs, nsent,
-                            jnp.ones((p_local,), jnp.int32), nch)
+                            jnp.ones((p_local,), jnp.int32), nch,
+                            jnp.int32(1))
                 return r2, delta, step + 1, ~chg, tele
 
             r, delta, steps, _, tele = jax.lax.while_loop(
@@ -818,7 +840,7 @@ class GopherEngine:
             x = state0["x"].reshape(-1, Q)
             ch = state0["changed_v"].reshape(-1, Q)
             fr = state0["frontier"].reshape(-1, Q)
-            pairs0, nsent0 = mega.round_stats(ch, cm)
+            pairs0, nsent0 = round_stats(ch, cm)
             tele0 = base_tele(pairs0, nsent0)
 
             def cond(c):
@@ -827,13 +849,14 @@ class GopherEngine:
 
             def body(c):
                 x, ch, fr, step, _, tele = c
-                x2, ch2, fl, li = mega.megastep_semiring_batched(
+                x2, ch2, fl, li, sweeps = mega.megastep_semiring_batched(
                     x, ch, fr, cm, semiring, unroll=unroll)
-                pairs, nsent = mega.round_stats(ch2, cm)
-                chpq = jnp.any(ch2.reshape(p_local, v_max, Q), axis=1)
-                changed_q = jnp.any(chpq, axis=0)
-                nch = jnp.sum(jnp.any(chpq, axis=-1).astype(jnp.int32))
-                tele = fold(tele, step, pairs, nsent, li, nch)
+                pairs, nsent = round_stats(ch2, cm)
+                with jax.named_scope("gopher.frontier"):
+                    chpq = jnp.any(ch2.reshape(p_local, v_max, Q), axis=1)
+                    changed_q = jnp.any(chpq, axis=0)
+                    nch = jnp.sum(jnp.any(chpq, axis=-1).astype(jnp.int32))
+                tele = fold(tele, step, pairs, nsent, li, nch, sweeps)
                 tele["qsteps"] = jnp.where(changed_q, step + 1,
                                            tele["qsteps"])
                 return x2, ch2, fl, step + 1, ~jnp.any(changed_q), tele
@@ -850,14 +873,15 @@ class GopherEngine:
         x = state0["x"].reshape(-1)
         ch = state0["changed_v"].reshape(-1)
         fr = state0["frontier"].reshape(-1)
-        pairs0, nsent0 = mega.round_stats(ch, cm)
+        pairs0, nsent0 = round_stats(ch, cm)
         tele0 = base_tele(pairs0, nsent0)
 
-        def sem_fold(tele, step, ch2, li):
-            pairs, nsent = mega.round_stats(ch2, cm)
-            nch = jnp.sum(jnp.any(ch2.reshape(p_local, v_max),
-                                  axis=1).astype(jnp.int32))
-            return fold(tele, step, pairs, nsent, li, nch), nch
+        def sem_fold(tele, step, ch2, li, sweeps):
+            pairs, nsent = round_stats(ch2, cm)
+            with jax.named_scope("gopher.frontier"):
+                nch = jnp.sum(jnp.any(ch2.reshape(p_local, v_max),
+                                      axis=1).astype(jnp.int32))
+            return fold(tele, step, pairs, nsent, li, nch, sweeps), nch
 
         def cond(c):
             _, _, _, step, done, _ = c
@@ -865,10 +889,10 @@ class GopherEngine:
 
         def bsp_body(c):
             x, ch, fr, step, _, tele = c
-            x2, ch2, fl, li = mega.megastep_semiring(
+            x2, ch2, fl, li, sweeps = mega.megastep_semiring(
                 x, ch, fr, cm, semiring, unroll=unroll,
                 backend=prog.spmv_backend, interpret=prog.interpret)
-            tele, nch = sem_fold(tele, step, ch2, li)
+            tele, nch = sem_fold(tele, step, ch2, li, sweeps)
             return x2, ch2, fl, step + 1, nch == 0, tele
 
         # resident narrow-phase gate: the earliest superstep from which
@@ -898,10 +922,12 @@ class GopherEngine:
                 x2, ch2, fr2, it, li = mega.resident_megastep_pallas(
                     x, ch, fr, cm, semiring, max_steps=max_s - enter,
                     interpret=prog.interpret)
-                pairs, nsent = mega.round_stats(ch2, cm)
+                pairs, nsent = round_stats(ch2, cm)
+                # one sweep per resident round
                 tele = dict(tele, liters=tele["liters"] + li,
                             sent=tele["sent"] + nsent,
-                            pairs=tele["pairs"] + pairs)
+                            pairs=tele["pairs"] + pairs,
+                            lsweeps=tele["lsweeps"] + it)
                 carry = (x2, ch2, fr2, step + it,
                          done | ~jnp.any(ch2), tele)
             else:
@@ -910,7 +936,7 @@ class GopherEngine:
                     x2, ch2, fr2, ap = mega.resident_step_semiring(
                         x, ch, fr, cm, semiring)
                     tele, nch = sem_fold(tele, step, ch2,
-                                         ap.astype(jnp.int32))
+                                         ap.astype(jnp.int32), jnp.int32(1))
                     return x2, ch2, fr2, step + 1, nch == 0, tele
 
                 carry = jax.lax.while_loop(cond, res_body, carry)
@@ -1101,8 +1127,10 @@ class GopherEngine:
         if self.tracer.enabled:
             state, steps, tele = self._run_traced(gb, num_queries=None)
         else:
-            state, steps, tele = self._runner(gb_example=gb)(gb)
-        state, t = self._finish(state, steps, tele, gb, num_queries=None)
+            with self._step("dispatch"):
+                state, steps, tele = self._runner(gb_example=gb)(gb)
+        with self._step("download"):
+            state, t = self._finish(state, steps, tele, gb, num_queries=None)
         self._record_run_metrics(t)
         return state, t
 
@@ -1128,9 +1156,11 @@ class GopherEngine:
         if self.tracer.enabled:
             state, steps, tele = self._run_traced(gb, num_queries=Q)
         else:
-            state, steps, tele = self._runner(num_queries=Q,
-                                              gb_example=gb)(gb)
-        state, t = self._finish(state, steps, tele, gb, num_queries=Q)
+            with self._step("dispatch"):
+                state, steps, tele = self._runner(num_queries=Q,
+                                                  gb_example=gb)(gb)
+        with self._step("download"):
+            state, t = self._finish(state, steps, tele, gb, num_queries=Q)
         self._record_run_metrics(t)
         return state, t
 
@@ -1216,6 +1246,8 @@ class GopherEngine:
         m.counter("engine_dense_retry_steps_total",
                   lab).inc(t.dense_retry_steps)
         m.histogram("engine_run_supersteps", lab).observe(t.supersteps)
+        if t.lockstep_sweeps is not None:
+            m.histogram("engine_lockstep_sweeps").observe(t.lockstep_sweeps)
         m.gauge("engine_partition_imbalance", lab).set(
             obs_skew.imbalance_score(t.local_iters))
 
@@ -1337,7 +1369,6 @@ class GopherEngine:
                          backend=self.backend):
                 stages.append(self._traced_stage_fns(
                     Q, k if phased else None))
-        tr.count("stage_builds", K)
 
         with tr.span("init"):
             state = tr.sync(stages[0]["init"](gb))
@@ -1534,7 +1565,7 @@ class GopherEngine:
                 pairs, nsent = mega.round_stats(None, cm)
                 chinfo = jnp.broadcast_to(chg, (p_local,))
                 return ((r2, delta), jnp.ones((p_local,), jnp.int32),
-                        pairs, nsent, chinfo)
+                        pairs, nsent, chinfo, jnp.int32(1))
 
             def finish(flat):
                 return {"r": flat[0].reshape(p_local, v_max),
@@ -1560,12 +1591,12 @@ class GopherEngine:
             def step_fn(gb, cma, flat, step):
                 cm = with_statics(cma)
                 x, ch, fr = flat
-                x2, ch2, fl, li = mk(x, ch, fr, cm, semiring,
-                                     unroll=unroll)
+                x2, ch2, fl, li, sweeps = mk(x, ch, fr, cm, semiring,
+                                             unroll=unroll)
                 pairs, nsent = mega.round_stats(ch2, cm)
                 chinfo = jnp.any(
                     ch2.reshape((p_local, v_max) + tail), axis=1)
-                return (x2, ch2, fl), li, pairs, nsent, chinfo
+                return (x2, ch2, fl), li, pairs, nsent, chinfo, sweeps
 
             def finish(flat):
                 return {k: v.reshape((p_local, v_max) + tail)
@@ -1596,7 +1627,6 @@ class GopherEngine:
         with tr.span("plan", phase=0, exchange="megastep",
                      backend=self.backend):
             fns = self._traced_stage_fns_megastep(Q)
-        tr.count("stage_builds", 1)
 
         with tr.span("init"):
             cma = fns["prep"](gb)
@@ -1610,6 +1640,7 @@ class GopherEngine:
         pairs_acc = np.asarray(pairs0, np.int64)
         chist[0] = int(pairs_acc.sum())
         sent = int(nsent0)
+        lsweeps = 0
         qsteps = np.zeros(Q, np.int64) if Q is not None else None
         psec = np.zeros(num_parts, np.float64)
         part_verts = tuple(int(x) for x in
@@ -1633,7 +1664,7 @@ class GopherEngine:
                                        part_verts=part_verts,
                                        num_devices=1)
                     with tr.span("megastep"):
-                        flat, li, pairs, nsent, chinfo = fns["step"](
+                        flat, li, pairs, nsent, chinfo, sweeps = fns["step"](
                             gb, cma, flat, jnp.int32(step))
                         tr.sync(li)
                     with tr.span("halt-vote"):
@@ -1658,6 +1689,7 @@ class GopherEngine:
                         if 0 <= p < num_parts:
                             psec[p] += s
                     liters += li_np
+                    lsweeps += int(sweeps)
                     hist[step] = nchanged
                     chist[step + 1] = int(p.sum())
                     pairs_acc += p
@@ -1667,7 +1699,8 @@ class GopherEngine:
                     done = not any_changed
 
         tele = dict(liters=liters, hist=hist, whist=whist, sent=sent,
-                    wire=0, chist=chist, pairs=pairs_acc, psec=psec)
+                    wire=0, chist=chist, pairs=pairs_acc, psec=psec,
+                    lsweeps=lsweeps)
         if Q is not None:
             tele["qsteps"] = qsteps
         return fns["finish"](flat), step, tele
@@ -1731,6 +1764,8 @@ class GopherEngine:
         )
         if "psec" in tele:
             t.part_seconds = np.asarray(tele["psec"], np.float64).reshape(-1)
+        if "lsweeps" in tele:
+            t.lockstep_sweeps = int(tele["lsweeps"])
         if phased:
             # phase buckets travel parts-leading (P, K, P); report (K, P, P)
             by_phase = np.transpose(pair_slots, (1, 0, 2))
@@ -1824,8 +1859,11 @@ class GopherEngine:
                 validate_engine(slim, num_queries=num_queries,
                                 gb_example=gb_example)
             if self.backend == "local":
-                cached = jax.jit(functools.partial(
-                    slim._run_batched, num_queries=num_queries))
+                loop = functools.partial(slim._run_batched,
+                                         num_queries=num_queries)
+                # the compiled module's name, and the trace's: jit_gopher_<exchange>
+                loop.__name__ = f"gopher_{exchange}"
+                cached = jax.jit(loop)
             else:
                 cached = slim._sharded_fn(
                     num_queries=num_queries, gb_example=gb_example)
@@ -1986,6 +2024,7 @@ class GopherEngine:
             state, steps, tele = self._run_batched(gb_shard,
                                                    num_queries=num_queries)
             return state, steps, tele
+        body.__name__ = f"gopher_{self.exchange}"
 
         gb_shapes = (graph_block(self.pg, as_spec=True) if gb_example is None
                      else {k: jax.ShapeDtypeStruct(v.shape, v.dtype)

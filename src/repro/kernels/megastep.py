@@ -39,6 +39,14 @@ dense-retry exact; see analysis.semiring). PageRank's ``sum`` ⊕ folds the
 dangling/delta reductions in a different association, so its parity class
 is allclose, mirroring the existing cross-mode contract.
 
+Stage names: the fused loops open ``jax.named_scope`` stages, which
+reach only the compiled HLO's metadata (``obs.op_stages`` reads them back
+per instruction): ``gopher.deliver`` (mailbox delivery and the inbox
+⊕-combine), ``gopher.sweep`` (the masked sweeps of the fixpoint),
+``gopher.frontier`` (frontier updates and the fixpoint's ``any(f)``),
+``gopher.stats`` (round statistics and telemetry) and ``gopher.update``
+(PageRank's contributions, dangling mass, rank update and delta).
+
 Delivery-order note: the staged engine exchanges AFTER superstep s and
 primes round 0 from the init state. The fused loop instead delivers at
 the TOP of superstep s from the previous superstep's ``changed_v`` — the
@@ -350,8 +358,9 @@ def megastep_semiring(x, changed, frontier, cm: dict, semiring: str,
     """One fused superstep for scalar idempotent-semiring programs on flat
     state: deliver the previous round's messages, ⊕-combine, run the
     masked local fixpoint, emit the new send set. Returns
-    ``(x2, changed2, f_left, liters)`` with liters per partition matching
-    the staged vmapped while_loop's select semantics bit for bit.
+    ``(x2, changed2, f_left, liters, sweeps)`` with liters per partition
+    matching the staged vmapped while_loop's select semantics bit for bit,
+    and ``sweeps`` the sweeps the flat fixpoint ran in lockstep.
     Runs the jnp oracle unless ``backend="pallas"`` asks for the
     megakernel (compiled, or interpreted with ``interpret=True``)."""
     if backend == "pallas":
@@ -360,61 +369,58 @@ def megastep_semiring(x, changed, frontier, cm: dict, semiring: str,
             interpret=interpret)
     combine = "min" if semiring == "min_plus" else "max"
     vm = cm["vmask"]
+    sweep = functools.partial(sweep_flat, cm=cm, semiring=semiring)
+    return _fused_fixpoint(x, changed, frontier, cm, combine, vm, sweep,
+                           unroll, semiring == "min_plus")
+
+
+def _fused_fixpoint(x, changed, frontier, cm: dict, combine: str, vm, sweep,
+                    unroll: int, with_weight: bool):
+    """The body both fused semiring supersteps share: delivery, the masked
+    fixpoint of ``sweep(x, f)`` in lockstep over every partition, and the
+    new send set, each under its stage name."""
     P = cm["num_parts"]
-    inbox = deliver_flat(x, changed, cm, combine, semiring == "min_plus")
-    x1 = _ew(combine, x, inbox)
-    f0 = frontier | ((x1 != x) & vm)
+    with jax.named_scope("gopher.deliver"):
+        inbox = deliver_flat(x, changed, cm, combine, with_weight)
+        x1 = _ew(combine, x, inbox)
+    with jax.named_scope("gopher.frontier"):
+        f0 = frontier | ((x1 != x) & vm)
 
     def cond(c):
         _, f, it, _ = c
-        return jnp.any(f) & (it < jnp.int32(_MAX_IT))
+        with jax.named_scope("gopher.frontier"):
+            return jnp.any(f) & (it < jnp.int32(_MAX_IT))
 
     def body(c):
         xc, f, it, li = c
-        li = li + jnp.int32(unroll) * jnp.any(f.reshape(P, -1), axis=1)
+        with jax.named_scope("gopher.stats"):
+            li = li + jnp.int32(unroll) * jnp.any(f.reshape(P, -1), axis=1)
         for _ in range(unroll):
-            y = sweep_flat(xc, f, cm, semiring)
-            x2 = _ew(combine, xc, y)
-            f = (x2 != xc) & vm
+            with jax.named_scope("gopher.sweep"):
+                x2 = _ew(combine, xc, sweep(xc, f))
+            with jax.named_scope("gopher.frontier"):
+                f = (x2 != xc) & vm
             xc = x2
         return xc, f, it + jnp.int32(unroll), li
 
-    x2, f_left, _, liters = jax.lax.while_loop(
+    x2, f_left, sweeps, liters = jax.lax.while_loop(
         cond, body, (x1, f0, jnp.int32(0), jnp.zeros((P,), jnp.int32)))
-    changed2 = (x2 != x) & vm
-    return x2, changed2, f_left, liters
+    with jax.named_scope("gopher.frontier"):
+        changed2 = (x2 != x) & vm
+    return x2, changed2, f_left, liters, sweeps
 
 
 def megastep_semiring_batched(x, changed, frontier, cm: dict, semiring: str,
                               unroll: int = 2):
     """Q-query fused superstep on flat (n, Q) state — the serving hot path.
     Mirrors serving.batched.BatchedSemiringProgram's superstep + the staged
-    batched exchange lane for lane."""
+    batched exchange lane for lane. Returns what
+    :func:`megastep_semiring` returns."""
     combine = "min" if semiring == "min_plus" else "max"
     vm = cm["vmask"][:, None]
-    P = cm["num_parts"]
-    inbox = deliver_flat(x, changed, cm, combine, semiring == "min_plus")
-    x1 = _ew(combine, x, inbox)
-    f0 = frontier | ((x1 != x) & vm)
-
-    def cond(c):
-        _, f, it, _ = c
-        return jnp.any(f) & (it < jnp.int32(_MAX_IT))
-
-    def body(c):
-        xc, f, it, li = c
-        li = li + jnp.int32(unroll) * jnp.any(f.reshape(P, -1), axis=1)
-        for _ in range(unroll):
-            y = sweep_flat_batched(xc, f, cm, semiring)
-            x2 = _ew(combine, xc, y)
-            f = (x2 != xc) & vm
-            xc = x2
-        return xc, f, it + jnp.int32(unroll), li
-
-    x2, f_left, _, liters = jax.lax.while_loop(
-        cond, body, (x1, f0, jnp.int32(0), jnp.zeros((P,), jnp.int32)))
-    changed2 = (x2 != x) & vm
-    return x2, changed2, f_left, liters
+    sweep = functools.partial(sweep_flat_batched, cm=cm, semiring=semiring)
+    return _fused_fixpoint(x, changed, frontier, cm, combine, vm, sweep,
+                           unroll, semiring == "min_plus")
 
 
 def megastep_pagerank(r, cm: dict, deg, tele, n_global: int, damping: float,
@@ -428,17 +434,23 @@ def megastep_pagerank(r, cm: dict, deg, tele, n_global: int, damping: float,
     float and collective lowering may re-associate)."""
     vm = cm["vmask"]
     P = cm["num_parts"]
-    contrib = jnp.where(deg > 0, r / jnp.maximum(deg, 1.0), 0.0)
-    pull = sweep_flat_dense(contrib, cm)
-    inbox = deliver_flat(contrib, None, cm, "sum", False)
-    dangling = jnp.sum(jnp.sum(
-        jnp.where(vm & (deg == 0), r, 0.0).reshape(P, -1), axis=1))
-    r_new = jnp.where(
-        vm,
-        (1.0 - damping) * tele + damping * (pull + inbox + dangling * tele),
-        0.0)
-    delta = jnp.sum(jnp.sum(jnp.abs(r_new - r).reshape(P, -1), axis=1))
-    changed = step + 1 < num_iters
+    with jax.named_scope("gopher.update"):
+        contrib = jnp.where(deg > 0, r / jnp.maximum(deg, 1.0), 0.0)
+    with jax.named_scope("gopher.sweep"):
+        pull = sweep_flat_dense(contrib, cm)
+    with jax.named_scope("gopher.deliver"):
+        inbox = deliver_flat(contrib, None, cm, "sum", False)
+    with jax.named_scope("gopher.update"):
+        dangling = jnp.sum(jnp.sum(
+            jnp.where(vm & (deg == 0), r, 0.0).reshape(P, -1), axis=1))
+        r_new = jnp.where(
+            vm,
+            (1.0 - damping) * tele
+            + damping * (pull + inbox + dangling * tele),
+            0.0)
+        delta = jnp.sum(jnp.sum(jnp.abs(r_new - r).reshape(P, -1), axis=1))
+    with jax.named_scope("gopher.frontier"):
+        changed = step + 1 < num_iters
     return r_new, delta, changed
 
 
@@ -453,14 +465,18 @@ def resident_step_semiring(x, changed, frontier, cm: dict, semiring: str):
     later staged superstep can take over mid-stream."""
     combine = "min" if semiring == "min_plus" else "max"
     vm = cm["vmask"]
-    inbox = deliver_flat(x, changed, cm, combine, semiring == "min_plus")
-    x1 = _ew(combine, x, inbox)
-    f = frontier | ((x1 != x) & vm)
-    y = sweep_flat(x1, f, cm, semiring)
-    x2 = _ew(combine, x1, y)
-    changed2 = (x2 != x) & vm
-    frontier2 = (x2 != x1) & vm
-    active_p = jnp.any(f.reshape(cm["num_parts"], -1), axis=1)
+    with jax.named_scope("gopher.deliver"):
+        inbox = deliver_flat(x, changed, cm, combine, semiring == "min_plus")
+        x1 = _ew(combine, x, inbox)
+    with jax.named_scope("gopher.frontier"):
+        f = frontier | ((x1 != x) & vm)
+    with jax.named_scope("gopher.sweep"):
+        x2 = _ew(combine, x1, sweep_flat(x1, f, cm, semiring))
+    with jax.named_scope("gopher.frontier"):
+        changed2 = (x2 != x) & vm
+        frontier2 = (x2 != x1) & vm
+    with jax.named_scope("gopher.stats"):
+        active_p = jnp.any(f.reshape(cm["num_parts"], -1), axis=1)
     return x2, changed2, frontier2, active_p
 
 
@@ -529,7 +545,7 @@ def _sweep_kernel_vals(xc, f, nbr, nok, wgt, semiring):
 def _megastep_kernel(x_ref, ch_ref, fr_ref, vm_ref, nbr_ref, nok_ref,
                      wgt_ref, lsrc_ref, lok_ref, lw_ref, hsrc_ref, hok_ref,
                      hw_ref, hrow_ref, hrok_ref,
-                     xo_ref, cho_ref, fro_ref, lit_ref,
+                     xo_ref, cho_ref, fro_ref, lit_ref, it_ref,
                      *, semiring, num_parts, unroll):
     x0 = x_ref[...]
     vmb = vm_ref[...] > 0.0
@@ -558,13 +574,14 @@ def _megastep_kernel(x_ref, ch_ref, fr_ref, vm_ref, nbr_ref, nok_ref,
             xc = x2
         return xc, f, it + jnp.int32(unroll), li
 
-    x2, f_left, _, li = jax.lax.while_loop(
+    x2, f_left, it, li = jax.lax.while_loop(
         cond, body,
         (x1, f0, jnp.int32(0), jnp.zeros((num_parts,), jnp.int32)))
     xo_ref[...] = x2
     cho_ref[...] = ((x2 != x0) & vmb).astype(jnp.float32)
     fro_ref[...] = f_left
     lit_ref[...] = li
+    it_ref[...] = jnp.full((1,), it, jnp.int32)
 
 
 def _resident_kernel(x_ref, ch_ref, fr_ref, vm_ref, nbr_ref, nok_ref,
@@ -629,28 +646,31 @@ def megastep_semiring_pallas(x, changed, frontier, cm: dict, semiring: str,
                              unroll: int = 1, interpret: bool = False):
     """The fused superstep as ONE Pallas launch: mailbox delivery, inbox
     combine, masked local fixpoint, and the changed/halt partial reduction
-    all execute against VMEM-resident state."""
+    all execute against VMEM-resident state. Returns what
+    :func:`megastep_semiring` returns."""
     n = x.shape[0]
     P = cm["num_parts"]
     ops = _mega_operands(x, changed, frontier, cm)
     import functools
     kernel = functools.partial(_megastep_kernel, semiring=semiring,
                                num_parts=P, unroll=unroll)
-    x2, ch, fr, li = pl.pallas_call(
+    x2, ch, fr, li, it = pl.pallas_call(
         kernel,
         grid=(1,),
         in_specs=_full_specs(ops),
         out_specs=[pl.BlockSpec((n,), lambda i: (0,)),
                    pl.BlockSpec((n,), lambda i: (0,)),
                    pl.BlockSpec((n,), lambda i: (0,)),
-                   pl.BlockSpec((P,), lambda i: (0,))],
+                   pl.BlockSpec((P,), lambda i: (0,)),
+                   pl.BlockSpec((1,), lambda i: (0,))],
         out_shape=[jax.ShapeDtypeStruct((n,), x.dtype),
                    jax.ShapeDtypeStruct((n,), jnp.float32),
                    jax.ShapeDtypeStruct((n,), jnp.float32),
-                   jax.ShapeDtypeStruct((P,), jnp.int32)],
+                   jax.ShapeDtypeStruct((P,), jnp.int32),
+                   jax.ShapeDtypeStruct((1,), jnp.int32)],
         interpret=interpret,
     )(*ops)
-    return x2, ch > 0.0, fr > 0.0, li
+    return x2, ch > 0.0, fr > 0.0, li, it[0]
 
 
 def resident_megastep_pallas(x, changed, frontier, cm: dict, semiring: str,

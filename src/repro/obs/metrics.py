@@ -9,7 +9,9 @@ Producers (all host-side, all O(1) per run/request — there is nothing to
 disable because nothing touches compiled code):
 
   * the engine feeds per-run superstep/wire/spill/retry/escalation totals
-    (``GopherEngine._finish``);
+    and the lockstep sweep count (``GopherEngine._record_run_metrics``);
+  * ``obs.trace.step`` feeds the seconds of every program span
+    (``gopher_span_seconds{span=...}``);
   * ``core.tiers`` feeds plan-build counts and EWMA-drift gauges
     (how far observations moved the traffic profile — the signal that a
     plan rebuild is due);
@@ -127,6 +129,16 @@ class MetricsRegistry:
             if m is None:
                 m = self._histograms[k] = Histogram(self._histogram_window)
             return m
+
+    def recent(self, name: str, n: int,
+               labels: Optional[dict] = None) -> list:
+        """The newest ``n`` observations of a histogram, oldest first;
+        [] where it has none (the histogram is not created)."""
+        with self._lock:
+            h = self._histograms.get(_key(name, labels))
+            if h is None or n <= 0:
+                return []
+            return list(h.window)[-n:]
 
     # ---------------- export ----------------
     def snapshot(self) -> dict:

@@ -35,16 +35,36 @@ through its dispatch points unconditionally:
 ``jax_profiler_dir`` arms the optional device-side capture: the run span
 wraps itself in ``jax.profiler.trace`` so a Perfetto-compatible XLA trace
 lands next to the host spans.
+
+The engine's own host steps go through :class:`step`, which is always on
+and never switches drivers: a ``gopher.<name>`` span on the profiler's
+clock (``jax.profiler.TraceAnnotation``, which records nothing unless a
+profiler is capturing), a sample of the ``gopher_span_seconds{span=<name>}``
+histogram, and a span in the tracer when one is enabled. Its device half
+is :func:`op_stages`: the ``gopher.*`` named scopes of the compiled BSP
+loops, read back from their HLO metadata, per instruction.
 """
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import json
+import re
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
+from repro.obs.metrics import default_registry
+
 __all__ = ["Span", "Tracer", "NOOP", "get_tracer", "set_tracer",
-           "validate_chrome_trace"]
+           "validate_chrome_trace", "step", "op_stages", "hlo_stages",
+           "SPAN_PREFIX", "SPAN_SECONDS"]
+
+#: every program span and device stage name starts with this
+SPAN_PREFIX = "gopher."
+#: the histogram every :class:`step` observes its seconds into
+SPAN_SECONDS = "gopher_span_seconds"
 
 
 @dataclasses.dataclass
@@ -214,6 +234,103 @@ def set_tracer(tracer: Optional[Tracer]) -> Tracer:
     global _default
     _default = tracer if tracer is not None else NOOP
     return _default
+
+
+# ---------------- program spans on the profiler's clock ----------------
+
+class step:
+    """A host step of the program, as a context manager: the span
+    ``gopher.<name>`` on the profiler's clock, one sample of
+    ``gopher_span_seconds{span=<name>}`` in ``metrics`` (default: the
+    process registry), and a span in ``tracer`` (default: the process
+    tracer) when that is enabled. It syncs nothing and switches nothing."""
+    __slots__ = ("name", "tracer", "metrics", "_ann", "_span", "_t0")
+
+    def __init__(self, name: str, tracer: Optional[Tracer] = None,
+                 metrics=None):
+        self.name = name
+        self.tracer = tracer
+        self.metrics = metrics
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(SPAN_PREFIX + self.name)
+        self._ann.__enter__()
+        tr = self.tracer if self.tracer is not None else _default
+        self._span = (tr.span(SPAN_PREFIX + self.name) if tr.enabled
+                      else _NOOP_SPAN)
+        self._span.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter_ns() - self._t0
+        self._span.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        reg = self.metrics if self.metrics is not None else default_registry()
+        reg.histogram(SPAN_SECONDS, {"span": self.name}).observe(dt / 1e9)
+        return False
+
+
+# ---------------- device stages of the compiled loops ----------------
+
+_HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)", re.M)
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*?"
+                     r"metadata=\{[^}]*?op_name=\"([^\"]*)\"", re.M)
+
+
+def hlo_stages(hlo_text: str):
+    """``(module name, {instruction: stage})`` of one compiled module's
+    HLO text: an instruction's stage is the innermost ``gopher.*``
+    component of its ``op_name`` metadata (a fusion carries its root's);
+    instructions outside every named stage are left out."""
+    m = _HLO_MODULE.search(hlo_text)
+    stages = {}
+    for name, op_name in _HLO_OP.findall(hlo_text):
+        scopes = [c for c in op_name.split("/") if c.startswith(SPAN_PREFIX)]
+        if scopes:
+            stages[name] = scopes[-1]
+    return (m.group(1) if m else ""), stages
+
+
+def op_stages(cache: Optional[dict] = None) -> Dict[str, Dict[str, str]]:
+    """``{module name: {HLO instruction: stage}}`` for every compiled BSP
+    loop in ``cache`` (default: the engine's runner cache), under the
+    module and instruction names a profiler trace labels device ops with.
+
+    Each loop is lowered again from its cache key's shapes (and the
+    mesh's sharding on the shard_map backend), identical lowerings are
+    compiled once, and the stages are read from the compiled HLO's
+    metadata. It compiles, so call it off the clock. Where two modules of
+    one name disagree on an instruction, the instruction is left out."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+    if cache is None:
+        from repro.core import engine
+        cache = engine._RUNNER_CACHE
+    seen, out, clash = set(), {}, set()
+    for key, runner in list(cache.items()):
+        backend, mesh, axis_name, gb_sig = key[1], key[7], key[6], key[-1]
+        if gb_sig is None:
+            continue
+        sharding = (NamedSharding(mesh, PartitionSpec(axis_name))
+                    if backend == "shard_map"
+                    and isinstance(mesh, jax.sharding.Mesh) else None)
+        spec = {k: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+                for k, shape, dtype in gb_sig}
+        lowered = runner.lower(spec)
+        digest = hashlib.sha256(lowered.as_text().encode()).hexdigest()
+        if digest in seen:
+            continue
+        seen.add(digest)
+        module, stages = hlo_stages(lowered.compile().as_text())
+        merged = out.setdefault(module, {})
+        for instr, stage in stages.items():
+            if merged.get(instr, stage) != stage:
+                clash.add((module, instr))
+            merged[instr] = stage
+    for module, instr in clash:
+        out[module].pop(instr, None)
+    return out
 
 
 # ---------------- schema validation (CI smoke) ----------------

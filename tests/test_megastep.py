@@ -224,14 +224,15 @@ def test_pallas_megastep_matches_oracle(road):
         ch = st0["changed_v"].reshape(-1)
         fr = st0["frontier"].reshape(-1)
         for _ in range(3):   # walk a few supersteps, compare each
-            xo, cho, fo, lo = mega.megastep_semiring(
+            xo, cho, fo, lo, so = mega.megastep_semiring(
                 x, ch, fr, cm, semiring, backend="jnp")
-            xp, chp, fp, lp = mega.megastep_semiring_pallas(
+            xp, chp, fp, lp, sp = mega.megastep_semiring_pallas(
                 x, ch, fr, cm, semiring, interpret=True)
             assert np.array_equal(np.asarray(xo), np.asarray(xp)), name
             assert np.array_equal(np.asarray(cho), np.asarray(chp)), name
             assert np.array_equal(np.asarray(fo), np.asarray(fp)), name
             assert np.array_equal(np.asarray(lo), np.asarray(lp)), name
+            assert int(so) == int(sp), name
             x, ch, fr = xo, cho, fo
 
 

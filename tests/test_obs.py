@@ -346,3 +346,176 @@ def test_tier_profile_drift_metrics(road):
         assert snap["gauges"]["tiers_profile_drift{profile=wire}"] > 0
     finally:
         obs_metrics.set_default_registry(old)
+
+
+# ---------------- program spans, device stages, lockstep count ----------------
+
+PREP_SPANS = ("engine", "layout", "upload", "compose_mailbox", "dispatch")
+PROGRAM_SPANS = ("entry",) + PREP_SPANS + ("download",)
+ANALYTICS = ("sssp", "pagerank")
+
+
+@pytest.fixture(scope="module")
+def mesh_graph():
+    """A graph of this section's own shapes, so the runner-cache entries
+    its analytics compile are told apart from other tests'."""
+    g = road_grid(9, 11, drop_frac=0.05, seed=2, weighted=True)
+    return partition_graph(g, bfs_grow_partition(g, 4, seed=0), 4)
+
+
+def _analytic(pg, name):
+    from repro import algorithms
+    if name == "sssp":
+        return algorithms.sssp(pg, 0)
+    return algorithms.pagerank(pg)
+
+
+def _own_loops(pg, name):
+    """This section's compiled BSP loops of analytic ``name``."""
+    from repro.core import PageRankProgram, engine
+    want = PageRankProgram if name == "pagerank" else SemiringProgram
+    return {k: v for k, v in engine._RUNNER_CACHE.items()
+            if isinstance(k[0], want) and k[2] == "megastep"
+            and k[8:11] == (pg.num_parts, pg.v_max, pg.mailbox_cap)}
+
+
+@pytest.mark.parametrize("name", ANALYTICS)
+def test_program_spans_reach_the_profiler(mesh_graph, name, tmp_path):
+    """Every gopher.* host span lands in a jax.profiler trace (read the
+    way the benchmark's trace reduction reads it), inside gopher.entry."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    _analytic(mesh_graph, name)                  # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        _analytic(mesh_graph, name)
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("gopher.")]
+    entries = [(s, e) for n, s, e in spans if n == "gopher.entry"]
+    assert len(entries) == 1
+    (lo, hi), = entries
+    found = {n for n, s, e in spans if lo <= s and e <= hi}
+    assert found == {"gopher." + s for s in PROGRAM_SPANS}
+    # the compiled loop is named, so its host span is too
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert "PjitFunction(gopher_megastep)" in names
+
+
+@pytest.mark.parametrize("name", ANALYTICS)
+def test_op_stages_name_the_hot_stages(mesh_graph, name):
+    from repro.obs import op_stages
+    _analytic(mesh_graph, name)
+    stages = op_stages(_own_loops(mesh_graph, name))
+    assert set(stages) == {"jit_gopher_megastep"}
+    found = set(stages["jit_gopher_megastep"].values())
+    assert {"gopher.sweep", "gopher.deliver", "gopher.stats"} <= found
+    assert found <= {"gopher.deliver", "gopher.sweep", "gopher.frontier",
+                     "gopher.stats", "gopher.update"}
+
+
+def test_hlo_stages_reads_the_innermost_scope():
+    from repro.obs.trace import hlo_stages
+    text = """HloModule jit_gopher_megastep, is_scheduled=true
+
+%body {
+  %fusion.27 = f32[8]{0} fusion(%p), kind=kLoop, calls=%f, metadata={op_name="jit(gopher_megastep)/while/body/gopher.sweep/while/body/gopher.frontier/ne" stack_frame_id=3}
+  ROOT %fusion.3 = f32[8]{0} fusion(%p), kind=kLoop, metadata={op_name="jit(gopher_megastep)/while/body/gopher.deliver/gather"}
+  %copy.1 = f32[8]{0} copy(%p), metadata={op_name="jit(gopher_megastep)/while/body/copy"}
+  %add.2 = s32[] add(%a, %b)
+}
+"""
+    module, stages = hlo_stages(text)
+    assert module == "jit_gopher_megastep"
+    assert stages == {"fusion.27": "gopher.frontier",
+                      "fusion.3": "gopher.deliver"}
+
+
+def _host_lockstep(pg):
+    """Lockstep sweeps of SSSP from 0, counted by a host loop over the
+    fused supersteps: the partitions' fixpoints run side by side and each
+    one's frontier stays empty once it empties, so a superstep's lockstep
+    sweeps are its busiest partition's."""
+    import jax
+    from repro.core.blocks import graph_block
+    from repro.kernels import megastep as mega
+    prog = SemiringProgram(
+        semiring="min_plus",
+        init_fn=make_sssp_init(int(pg.part_of[0]), int(pg.local_of[0])))
+    gb = graph_block(pg)
+    cm = mega.compose_mailbox(gb)
+    st = jax.vmap(prog.init)(gb)
+    x, ch, fr = (st[k].reshape(-1) for k in ("x", "changed_v", "frontier"))
+    step = jax.jit(lambda x, ch, fr: mega.megastep_semiring(
+        x, ch, fr, cm, "min_plus")[:4])
+    steps = total = 0
+    while True:
+        x, ch, fr, li = step(x, ch, fr)
+        steps += 1
+        total += int(np.asarray(li).max())
+        if not np.asarray(ch).any():
+            return steps, total
+
+
+@pytest.mark.parametrize("name", ANALYTICS)
+def test_lockstep_sweeps_count_the_flat_fixpoint(mesh_graph, name):
+    _, t = _analytic(mesh_graph, name)
+    if name == "pagerank":
+        assert t.lockstep_sweeps == t.supersteps == 30
+        return
+    li = np.asarray(t.local_iters)
+    assert li.max() <= t.lockstep_sweeps <= li.sum()
+    assert _host_lockstep(mesh_graph) == (t.supersteps, t.lockstep_sweeps)
+    # the traced stepped driver counts the same sweeps
+    _, tt = GopherEngine(mesh_graph, _prog(mesh_graph, "sssp"),
+                         tracer=Tracer(enabled=True)).run()
+    assert tt.lockstep_sweeps == t.lockstep_sweeps
+
+
+def test_staged_loops_carry_no_lockstep_count(mesh_graph):
+    _, t = GopherEngine(mesh_graph, _prog(mesh_graph, "sssp"),
+                        exchange="dense").run()
+    assert t.lockstep_sweeps is None
+
+
+@pytest.mark.parametrize("name", ANALYTICS)
+def test_each_run_samples_every_program_span(mesh_graph, name):
+    from repro.obs import SPAN_SECONDS
+    from repro.obs import metrics as obs_metrics
+    reg = MetricsRegistry()
+    old = obs_metrics.default_registry()
+    obs_metrics.set_default_registry(reg)
+    try:
+        runs = 3
+        for _ in range(runs):
+            _, t = _analytic(mesh_graph, name)
+        for span in PROGRAM_SPANS:
+            got = reg.recent(SPAN_SECONDS, 10, {"span": span})
+            assert len(got) == runs, span
+            assert all(s >= 0 for s in got)
+        assert reg.recent("engine_lockstep_sweeps", 10) \
+            == [float(t.lockstep_sweeps)] * runs
+        validate_metrics(reg.snapshot())
+    finally:
+        obs_metrics.set_default_registry(old)
+
+
+def test_step_records_into_an_enabled_tracer_only():
+    from repro.obs import step
+    reg = MetricsRegistry()
+    on, off = Tracer(enabled=True), Tracer(enabled=False)
+    with step("layout", tracer=on, metrics=reg):
+        with step("upload", tracer=on, metrics=reg):
+            pass
+    with step("layout", tracer=off, metrics=reg):
+        pass
+    assert [(s.name, s.depth) for s in on.spans] == [
+        ("gopher.upload", 1), ("gopher.layout", 0)]
+    assert on.balanced and off.spans == []
+    validate_chrome_trace(on.chrome_trace())
+    assert len(reg.recent("gopher_span_seconds", 5, {"span": "layout"})) == 2
+    assert reg.recent("gopher_span_seconds", 5, {"span": "absent"}) == []
