@@ -89,6 +89,28 @@ def _boundary_sources(pg: PartitionedGraph, reset: np.ndarray) -> np.ndarray:
     return out
 
 
+def resume_seed(pg: PartitionedGraph, prev_x: np.ndarray,
+                delta: DeltaResult, init_values: np.ndarray):
+    """The restart state of an incremental run, ``(x0, frontier0)``: the
+    previous fixpoint ``prev_x`` ((P, v_max) or per query lane (P, v_max,
+    Q)), with every sub-graph meta-reachable from a removal reset to
+    ``init_values``, and the seed frontier (P, v_max): the inserted edges'
+    sources, the reset vertices and the boundary sources into them.
+
+    The seed meets the frontier invariant that the masked sweeps rely on
+    (kernels.megastep.sweep_flat): every vertex left out of it has already
+    relaxed all its local out-neighbours, since a local in-neighbour of a
+    reset vertex lies in the same partition-local WCC and so is reset and
+    seeded too."""
+    x0 = np.array(prev_x, np.float32, copy=True)
+    frontier = np.asarray(delta.dirty_insert, bool).copy()
+    if delta.dirty_remove.any():
+        reset = _meta_reachable(pg, np.asarray(delta.dirty_remove, bool))
+        x0[reset] = init_values[reset]
+        frontier |= reset | _boundary_sources(pg, reset)
+    return x0, frontier & pg.vmask
+
+
 def _incremental_run(pg: PartitionedGraph, semiring: str, prev_x: np.ndarray,
                      delta: DeltaResult, init_values: np.ndarray,
                      backend: str = "local", mesh=None,
@@ -96,13 +118,7 @@ def _incremental_run(pg: PartitionedGraph, semiring: str, prev_x: np.ndarray,
                      max_local_iters: Optional[int] = None,
                      gb: Optional[dict] = None, exchange: str = "auto",
                      tier_plan=None):
-    x0 = np.array(prev_x, np.float32, copy=True)
-    frontier = np.asarray(delta.dirty_insert, bool).copy()
-    if delta.dirty_remove.any():
-        reset = _meta_reachable(pg, np.asarray(delta.dirty_remove, bool))
-        x0[reset] = init_values[reset]
-        frontier |= reset | _boundary_sources(pg, reset)
-    frontier &= pg.vmask
+    x0, frontier = resume_seed(pg, prev_x, delta, init_values)
     prog = SemiringProgram(semiring=semiring, resume=True,
                            spmv_backend=spmv_backend,
                            max_local_iters=max_local_iters)
@@ -180,13 +196,8 @@ def incremental_sssp_batched(pg: PartitionedGraph, sources_global,
     for p in range(P):
         m = pg.vmask[p]
         x0[p][m] = prev[:, pg.global_id[p][m]].T
-    frontier = np.asarray(delta.dirty_insert, bool).copy()
-    if delta.dirty_remove.any():
-        reset = _meta_reachable(pg, np.asarray(delta.dirty_remove, bool))
-        init = sssp_query_init(pg, sources_global)      # (P, v_max, L)
-        x0[reset] = init[reset]
-        frontier |= reset | _boundary_sources(pg, reset)
-    frontier &= pg.vmask
+    x0, frontier = resume_seed(pg, x0, delta,
+                               sssp_query_init(pg, sources_global))
     qf = np.broadcast_to(frontier[..., None], x0.shape)
     prog = BatchedSemiringProgram(semiring="min_plus", num_queries=L,
                                   resume=True)

@@ -48,6 +48,15 @@ class SemiringProgram:
     ~0 (kernels.semiring_spmv_frontier). For idempotent ⊕ the masked fixpoint
     is bitwise identical to the unmasked one.
 
+    The frontier keeps an invariant: every vertex outside it has already
+    relaxed all its local out-neighbours. Each seed meets it (all of
+    ``vmask``; a resume's seed from algorithms.incremental.resume_seed),
+    delivery adds every vertex the inbox lowered, and each sweep adds every
+    vertex it changed. The fused route's sweep relies on it: it gathers
+    only the frontier's values, ⊕-identity elsewhere (kernels.megastep.
+    sweep_flat), so a hand-made resume seed must cover the local
+    in-neighbours of every vertex whose value it raised.
+
     ``resume=True`` starts from a previous fixpoint: ``gb["x0"]`` is the prior
     state and ``gb["frontier0"]`` the dirty seed set (see gofs.temporal /
     algorithms.incremental); both arrive via ``GopherEngine.run(extra=...)``.

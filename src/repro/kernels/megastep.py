@@ -30,6 +30,15 @@ This module collapses the whole superstep into ONE dispatch over the flat
   :func:`resident_enter_round` gates on the ``PhasedTierPlan`` band
   geometry fitting :data:`MEGASTEP_VMEM_BUDGET`.
 
+Frontier invariant: every vertex outside the frontier has already
+relaxed all its local out-neighbours. Cold starts seed all of ``vmask``,
+delivery adds every vertex the inbox lowered, each sweep adds every vertex
+it changed, and incremental resumes seed whatever a delta left unrelaxed
+(algorithms.incremental.resume_seed). The masked sweeps rely on it: they
+gather only the frontier's values (the state with the ⊕-identity off the
+frontier), one gather per sweep, since under the invariant a settled
+in-neighbour cannot move its row (:func:`sweep_flat`).
+
 Exactness: for idempotent ⊕ (min/max) every value either path produces is
 a ⊕-fold of the same multiset of path sums, and float32 min/max are
 order-independent bit-for-bit — so the fused superstep, the resident
@@ -301,19 +310,25 @@ def round_stats(changed, cm: dict):
 
 # ---------------- flat frontier sweeps ----------------
 
-def sweep_flat(x, f, cm: dict, semiring: str):
-    """Frontier-masked ELL sweep over the flattened full adjacency —
-    row-for-row the math of kernels.ref.semiring_spmv_frontier_ref, so the
-    per-partition staged sweep and this flat one produce identical bits."""
-    ident = _KIDENT[semiring]
+def sweep_flat(xf, cm: dict, semiring: str):
+    """Frontier sweep over the flattened full adjacency: each row's ⊕ over
+    its in-neighbours' frontier values, one gather. ``xf`` is the state
+    with the ⊕-identity off the frontier (``where(f, x, ident)``), so a
+    lane whose source is settled contributes the identity and no separate
+    gather of the frontier flags is needed.
+
+    It relies on the frontier invariant: every vertex outside the frontier
+    has already relaxed all its local out-neighbours (``x[v] ⊕ (x[u] ⊗ w)
+    == x[v]`` for each local edge u -> v with u settled). Under it the
+    lanes this sweep drops could not move their row, so the result is not
+    row-for-row :func:`kernels.ref.semiring_spmv_frontier_ref` (which
+    reduces every lane of a row with an active in-neighbour) but equal to
+    it after the ⊕ with the state, bit for bit."""
     ok, idx = cm["nbr_ok"], cm["nbr"]
-    g = x[idx]
-    act = jnp.any(ok & f[idx], axis=1)
+    g = xf[idx]
     if semiring == "min_plus":
-        y = jnp.min(jnp.where(ok, g + cm["wgt"], jnp.inf), axis=1)
-    else:
-        y = jnp.max(jnp.where(ok, g, -jnp.inf), axis=1)
-    return jnp.where(act, y, ident)
+        return jnp.min(jnp.where(ok, g + cm["wgt"], jnp.inf), axis=1)
+    return jnp.max(jnp.where(ok, g, -jnp.inf), axis=1)
 
 
 def sweep_flat_dense(x, cm: dict):
@@ -325,22 +340,21 @@ def sweep_flat_dense(x, cm: dict):
     return jnp.sum(jnp.where(ok, g * ones, 0.0), axis=1)
 
 
-def sweep_flat_batched(x, f, cm: dict, semiring: str):
-    """Frontier-masked two-bin multi-query sweep over the flattened binned
-    adjacency — mirrors ops.binned_ell_spmv_multi_frontier (lo bin + hub
-    scatter merge) with flat indices."""
+def sweep_flat_batched(xf, cm: dict, semiring: str):
+    """Frontier sweep, lane by lane, over the flattened two-bin binned
+    adjacency on (n, Q) frontier values — :func:`sweep_flat` per query
+    lane, one gather per bin (lo bin + hub scatter merge, as
+    ops.binned_ell_spmv_multi_frontier has it, with flat indices). Equal to
+    that masked sweep after the ⊕ with the state, under the same frontier
+    invariant, lane by lane."""
     assert semiring in ("min_plus", "max_first")
-    ident = _KIDENT[semiring]
 
     def sweep(idx, ok, w):
-        act = jnp.any(ok[..., None] & f[idx], axis=1)       # (rows, Q)
-        g = x[idx]                                          # (rows, D, Q)
+        g = xf[idx]                                         # (rows, D, Q)
         if semiring == "min_plus":
-            y = jnp.min(jnp.where(ok[..., None], g + w[..., None], jnp.inf),
-                        axis=1)
-        else:
-            y = jnp.max(jnp.where(ok[..., None], g, -jnp.inf), axis=1)
-        return jnp.where(act, y, ident)
+            return jnp.min(jnp.where(ok[..., None], g + w[..., None],
+                                     jnp.inf), axis=1)
+        return jnp.max(jnp.where(ok[..., None], g, -jnp.inf), axis=1)
 
     y = sweep(cm["nbr_lo"], cm["nbr_lo_ok"], cm["wgt_lo"])
     yh = sweep(cm["ahub_nbr"], cm["ahub_ok"], cm["ahub_wgt"])
@@ -377,34 +391,51 @@ def megastep_semiring(x, changed, frontier, cm: dict, semiring: str,
 def _fused_fixpoint(x, changed, frontier, cm: dict, combine: str, vm, sweep,
                     unroll: int, with_weight: bool):
     """The body both fused semiring supersteps share: delivery, the masked
-    fixpoint of ``sweep(x, f)`` in lockstep over every partition, and the
-    new send set, each under its stage name."""
+    fixpoint of ``sweep(xf)`` in lockstep over every partition, and the
+    new send set, each under its stage name.
+
+    The loop carries the frontier twice: as flags ``f`` (the halt test and
+    the per-partition sweep counts read them) and as values ``xf``, the
+    state with the ⊕-identity off the frontier, which is all the sweep
+    gathers. ``xf`` is built once before the loop and then by each sweep's
+    elementwise tail, so the gather reads one carried buffer whatever
+    XLA's fusion would make of a mask computed in front of it (which could
+    gather the flags again). The masked sweep is exact under the frontier invariant (see
+    :func:`sweep_flat`), which every caller's seed meets and every sweep
+    keeps: a cold start seeds all of ``vmask``, delivery adds every vertex
+    the inbox lowered, each sweep puts every changed vertex into ``f``,
+    and an incremental resume seeds the new edges' sources and, for a
+    deletion, whole reset sub-graphs with their boundary sources."""
     P = cm["num_parts"]
+    ident = _IDENT[combine]
     with jax.named_scope("gopher.deliver"):
         inbox = deliver_flat(x, changed, cm, combine, with_weight)
         x1 = _ew(combine, x, inbox)
     with jax.named_scope("gopher.frontier"):
         f0 = frontier | ((x1 != x) & vm)
+        xf0 = jnp.where(f0, x1, ident)
 
     def cond(c):
-        _, f, it, _ = c
+        _, _, f, it, _ = c
         with jax.named_scope("gopher.frontier"):
             return jnp.any(f) & (it < jnp.int32(_MAX_IT))
 
     def body(c):
-        xc, f, it, li = c
+        xc, xf, f, it, li = c
         with jax.named_scope("gopher.stats"):
             li = li + jnp.int32(unroll) * jnp.any(f.reshape(P, -1), axis=1)
         for _ in range(unroll):
             with jax.named_scope("gopher.sweep"):
-                x2 = _ew(combine, xc, sweep(xc, f))
+                x2 = _ew(combine, xc, sweep(xf))
             with jax.named_scope("gopher.frontier"):
                 f = (x2 != xc) & vm
+                xf = jnp.where(f, x2, ident)
             xc = x2
-        return xc, f, it + jnp.int32(unroll), li
+        return xc, xf, f, it + jnp.int32(unroll), li
 
-    x2, f_left, sweeps, liters = jax.lax.while_loop(
-        cond, body, (x1, f0, jnp.int32(0), jnp.zeros((P,), jnp.int32)))
+    x2, _, f_left, sweeps, liters = jax.lax.while_loop(
+        cond, body,
+        (x1, xf0, f0, jnp.int32(0), jnp.zeros((P,), jnp.int32)))
     with jax.named_scope("gopher.frontier"):
         changed2 = (x2 != x) & vm
     return x2, changed2, f_left, liters, sweeps
@@ -470,8 +501,9 @@ def resident_step_semiring(x, changed, frontier, cm: dict, semiring: str):
         x1 = _ew(combine, x, inbox)
     with jax.named_scope("gopher.frontier"):
         f = frontier | ((x1 != x) & vm)
+        xf = jnp.where(f, x1, _IDENT[combine])
     with jax.named_scope("gopher.sweep"):
-        x2 = _ew(combine, x1, sweep_flat(x1, f, cm, semiring))
+        x2 = _ew(combine, x1, sweep_flat(xf, cm, semiring))
     with jax.named_scope("gopher.frontier"):
         changed2 = (x2 != x) & vm
         frontier2 = (x2 != x1) & vm

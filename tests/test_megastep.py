@@ -10,6 +10,7 @@ must match the compact path exactly so the tier-profile EWMAs keep
 learning from fused runs, while wire_slots/bytes_on_wire are zero.
 """
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -20,8 +21,9 @@ import jax.numpy as jnp
 from repro.core import (GopherEngine, PageRankProgram, PhasedTierPlan,
                         SemiringProgram, graph_block, init_max_vertex,
                         make_sssp_init)
-from repro.gofs import bfs_grow_partition, road_grid
-from repro.gofs.formats import partition_graph
+from repro.algorithms.incremental import _boundary_sources, resume_seed
+from repro.gofs import EdgeDelta, apply_delta, bfs_grow_partition, road_grid
+from repro.gofs.formats import PAD, partition_graph
 from repro.kernels import megastep as mega
 
 
@@ -130,16 +132,24 @@ def test_incremental_resume_rides_fused_route(road):
         exchange="dense").run()
     x_fix = np.asarray(fix["x"])
     prog = SemiringProgram(semiring="min_plus", resume=True)
-    # invalidate a patch of vertices and re-relax from the stale fixpoint
+    # invalidate a patch of vertices and re-relax from the stale fixpoint.
+    # The seed meets the frontier invariant of the masked sweep: the patch,
+    # its local in-neighbours and the remote sources into it, so every
+    # vertex left out has relaxed its out-edges
     x0 = np.where(pg.vmask, x_fix, np.inf).astype(np.float32)
-    fr0 = np.zeros_like(pg.vmask)
-    x0[1, :8] = np.inf
-    fr0[1, :8] = True
+    patch = np.zeros_like(pg.vmask)
+    patch[1, :8] = True
+    x0[patch] = np.inf
+    fr0 = patch | _boundary_sources(pg, patch)
+    nbr = pg.nbr[1][:8]
+    fr0[1, nbr[nbr != PAD]] = True
     extra = {"x0": x0, "frontier0": fr0}
     s_ref, _ = GopherEngine(pg, prog, exchange="dense").run(extra=extra)
     eng = GopherEngine(pg, prog, exchange="megastep")
     s, t = eng.run(extra=extra)
     assert np.array_equal(np.asarray(s["x"]), np.asarray(s_ref["x"]))
+    # ... and the re-relaxed patch lands back on the fixpoint
+    assert np.array_equal(np.asarray(s["x"])[pg.vmask], x_fix[pg.vmask])
     # quiesced resume: one superstep, zero local iterations, state unchanged
     s2, t2 = eng.run(extra={"x0": np.asarray(s["x"]),
                             "frontier0": np.zeros_like(pg.vmask)})
@@ -234,6 +244,175 @@ def test_pallas_megastep_matches_oracle(road):
             assert np.array_equal(np.asarray(lo), np.asarray(lp)), name
             assert int(so) == int(sp), name
             x, ch, fr = xo, cho, fo
+
+
+# ---------------- one gather per sweep: frontier values vs flags ----------
+
+def _two_gather_megastep(x, changed, frontier, cm, semiring, unroll):
+    """The fused superstep with the sweep that gathers the state and the
+    frontier flags apart, and reduces every lane of a row that has an
+    active in-neighbour (kernels.ref.semiring_spmv_frontier_ref's math)."""
+    combine = "min" if semiring == "min_plus" else "max"
+    red = jnp.min if combine == "min" else jnp.max
+    ident = mega._IDENT[combine]
+    batched = x.ndim == 2
+    vm = cm["vmask"][:, None] if batched else cm["vmask"]
+    P = cm["num_parts"]
+
+    def masked(xc, f, idx, ok, w):
+        okb, wb = (ok[..., None], w[..., None]) if batched else (ok, w)
+        act = jnp.any(okb & f[idx], axis=1)
+        g = xc[idx] + wb if semiring == "min_plus" else xc[idx]
+        return jnp.where(act, red(jnp.where(okb, g, ident), axis=1), ident)
+
+    def sweep(xc, f):
+        if not batched:
+            return masked(xc, f, cm["nbr"], cm["nbr_ok"], cm["wgt"])
+        y = masked(xc, f, cm["nbr_lo"], cm["nbr_lo_ok"], cm["wgt_lo"])
+        yh = masked(xc, f, cm["ahub_nbr"], cm["ahub_ok"], cm["ahub_wgt"])
+        ref = y.at[cm["ahub_dst"]]
+        return (ref.min if combine == "min" else ref.max)(yh, mode="drop")
+
+    inbox = mega.deliver_flat(x, changed, cm, combine,
+                              semiring == "min_plus")
+    x1 = mega._ew(combine, x, inbox)
+    f0 = frontier | ((x1 != x) & vm)
+
+    def body(c):
+        xc, f, it, li = c
+        li = li + jnp.int32(unroll) * jnp.any(f.reshape(P, -1), axis=1)
+        for _ in range(unroll):
+            x2 = mega._ew(combine, xc, sweep(xc, f))
+            f = (x2 != xc) & vm
+            xc = x2
+        return xc, f, it + jnp.int32(unroll), li
+
+    x2, f_left, sweeps, liters = jax.lax.while_loop(
+        lambda c: jnp.any(c[1]), body,
+        (x1, f0, jnp.int32(0), jnp.zeros((P,), jnp.int32)))
+    return x2, (x2 != x) & vm, f_left, liters, sweeps
+
+
+def _megastep_case(case, road):
+    """(cm, semiring, unroll, x, changed, frontier) of the case's first
+    superstep, flat as the fused loop carries them."""
+    g, pg = road
+    if case in ("sssp_cold", "cc_cold"):
+        prog = _programs(pg)[case[:-5]]
+        gb = graph_block(pg)
+        st = jax.vmap(prog.init)(gb)
+        return (mega.compose_mailbox(gb), prog.semiring, 1,
+                *(st[k].reshape(-1) for k in ("x", "changed_v", "frontier")))
+    if case == "sssp_batched":
+        from repro.serving.batched import sssp_query_init
+        x = sssp_query_init(pg, [0, 7, 19, 60]).reshape(-1, 4)
+        seed = np.broadcast_to(pg.vmask.reshape(-1, 1), x.shape)
+        cm = mega.compose_mailbox(graph_block(pg), adjacency="binned")
+        return cm, "min_plus", 2, jnp.asarray(x), seed, seed
+    # resumes: the previous fixpoint of pg, restarted on the delta's graph
+    rng = np.random.default_rng(4)
+    a = g.csr().tocoo()
+    if case == "sssp_insert":
+        delta = EdgeDelta.inserts(rng.integers(0, g.n, 6),
+                                  rng.integers(0, g.n, 6),
+                                  rng.uniform(0.5, 2.0, 6))
+        prog = _programs(pg)["sssp"]
+    else:                                           # cc_delete
+        und = np.flatnonzero(a.col < a.row)
+        pick = rng.choice(und, 12, replace=False)
+        delta = EdgeDelta.of(insert_src=[1], insert_dst=[90],
+                             insert_wgt=[1.0], remove_src=a.col[pick],
+                             remove_dst=a.row[pick])
+        prog = _programs(pg)["cc"]
+    res = apply_delta(pg, delta, directed=False)
+    assert res.dirty_remove.any() == (case == "cc_delete")
+    prev, _ = GopherEngine(pg, prog, exchange="dense").run()
+    gb = graph_block(res.pg)
+    init = np.asarray(jax.vmap(prog.init)(gb)["x"])
+    x0, fr = resume_seed(res.pg, np.asarray(prev["x"]), res, init)
+    return (mega.compose_mailbox(gb), prog.semiring, 1,
+            jnp.asarray(x0).reshape(-1), fr.reshape(-1), fr.reshape(-1))
+
+
+@pytest.mark.parametrize("case", ["sssp_cold", "cc_cold", "sssp_insert",
+                                  "cc_delete", "sssp_batched"])
+def test_one_gather_sweep_matches_two_gather_superstep(case, road):
+    """The fused superstep whose sweep gathers only the frontier's values
+    equals, superstep by superstep, the one that gathers state and flags
+    apart: state, send set, leftover frontier, per-partition sweep counts
+    and lockstep sweeps, from cold starts, incremental resumes and a
+    query batch."""
+    cm, semiring, unroll, x, ch, fr = _megastep_case(case, road)
+    fused = (mega.megastep_semiring_batched if x.ndim == 2
+             else mega.megastep_semiring)
+    new = jax.jit(lambda x, ch, fr: fused(x, ch, fr, cm, semiring,
+                                          unroll=unroll))
+    old = jax.jit(lambda x, ch, fr: _two_gather_megastep(
+        x, ch, fr, cm, semiring, unroll))
+    total = 0
+    for step in range(200):
+        a, b = new(x, ch, fr), old(x, ch, fr)
+        for name, u, v in zip(("x", "changed", "frontier", "liters",
+                               "sweeps"), a, b):
+            assert np.array_equal(np.asarray(u), np.asarray(v)), \
+                (case, step, name)
+        total += int(a[4])
+        x, ch, fr = a[:3]
+        if not np.asarray(ch).any():
+            break
+    assert step > 0 and total > 0, case        # the case did real work
+    assert not np.asarray(ch).any(), case      # ... and quiesced
+
+
+def _nbr_table_gathers(hlo: str, rows: int) -> int:
+    """Gathers of ``rows`` elements (one per lane of the (n, D) neighbour
+    table) in the fixpoint's while body and what it calls, from compiled
+    HLO text of a module with that one loop."""
+    comps, lines = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if m:
+            comps[m.group(1)] = lines = []
+        elif lines is not None:
+            lines.append(line)
+    body = re.findall(r" while\(.*body=%([\w.\-]+)", hlo)
+    assert len(body) == 1, body
+    seen, todo, count = set(), body, 0
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for ln in comps[c]:
+            todo += re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", ln)
+            m = re.search(r"= \w+\[([\d,]+)\][^ ]* gather\(", ln)
+            if m and np.prod([int(v) for v in m.group(1).split(",")]) \
+                    == rows:
+                count += 1
+    return count
+
+
+def test_fixpoint_sweep_gathers_the_neighbour_table_once(road):
+    """Compiled on the CPU, the scalar megastep's fixpoint loop gathers
+    over the (n, D) neighbour table once per sweep: the frontier's values,
+    not the state and the flags apart (XLA must not fuse the mask back in
+    front of the gather)."""
+    _, pg = road
+    gb = graph_block(pg)
+    cm = mega.compose_mailbox(gb)
+    st = jax.vmap(_programs(pg)["sssp"].init)(gb)
+    args = [st[k].reshape(-1) for k in ("x", "changed_v", "frontier")]
+    statics = {k: cm[k] for k in mega.MAILBOX_STATICS}
+    arrays = {k: v for k, v in cm.items() if k not in mega.MAILBOX_STATICS}
+
+    def gathers(step):
+        hlo = jax.jit(lambda x, ch, fr, a: step(
+            x, ch, fr, {**a, **statics}, "min_plus", 1)).lower(
+                *args, arrays).compile().as_text()
+        return _nbr_table_gathers(hlo, cm["nbr"].size)
+
+    assert gathers(mega.megastep_semiring) == 1
+    assert gathers(_two_gather_megastep) == 2   # the count sees both
 
 
 def test_engine_dispatches_pallas_backend(road):
