@@ -153,11 +153,20 @@ class Telemetry:
     # process can't see real per-partition compute splits). None on the
     # fused single-dispatch loops, which have no per-superstep host clock.
     part_seconds: Optional[np.ndarray] = None  # (P,) float64
-    # the sweeps the megastep's flat fixpoint ran in lockstep over every
-    # partition, summed over supersteps (PageRank: one per superstep) —
-    # what the device executed, where local_iters counts per partition.
-    # None on the staged loops, which carry no lockstep count.
+    # the sweeps the partitions' fixpoints ran in lockstep, summed over
+    # supersteps (PageRank: one per superstep) — what the slowest device
+    # executed, where local_iters counts per partition. A superstep's
+    # lockstep sweeps are its busiest partition's: on the megastep's flat
+    # state and on one device's vmapped partitions alike, and across a
+    # mesh, where every device waits at the superstep's collectives for
+    # the slowest one. None on the checkpointed driver.
     lockstep_sweeps: Optional[int] = None
+    # the staged loops' sweep slots in which a device had finished its own
+    # partitions' fixpoints and waited for the slowest device's: per
+    # superstep Σ_d (max_d m_d − m_d), m_d a device's busiest partition's
+    # sweeps, summed over supersteps. 0 on one device; None on the
+    # megastep and checkpointed drivers.
+    chip_wait_sweeps: Optional[int] = None
 
     @staticmethod
     def model_bytes(slots: int, num_parts: int, rounds: int, cap: int,
@@ -352,6 +361,11 @@ class GopherEngine:
         the tier table on a phased plan (one superstep body is traced per
         loop segment).
 
+        Its device stages carry the ``gopher.*`` named scopes that
+        ``repro.obs.op_stages`` reads back: the program's local fixpoint
+        is ``gopher.sweep``, and the exchange's halves are named by
+        make_exchange_stages.
+
         With ``num_queries=Q`` the program is query-batched: state/inbox
         leaves carry a QUERY-TRAILING (v_max, Q) shape per partition (Q rides
         the contiguous lane dimension), `changed` is per-partition per-query
@@ -460,6 +474,12 @@ class GopherEngine:
         ``route_extras`` is {} except on 'phased', where the per-superstep
         dense-retry decision lives on the route side: {'wire': the corrected
         shipped-slot count, 'dstep': the 0/1 retry flag}.
+
+        Named stages (``jax.named_scope``, HLO metadata only): ``pack`` is
+        ``gopher.pack``; ``route``'s collective transpose (the dense or
+        compact all_to_all, the tiered routing, the phased retry's
+        ``cond``) is ``gopher.route``; its inbox ⊕-combine is
+        ``gopher.deliver``.
         """
         prog = self.program
         cap = self.pg.mailbox_cap
@@ -485,6 +505,7 @@ class GopherEngine:
             limits_np = plan.limits()
             axis = self.axis_name if self.backend == "shard_map" else None
 
+        @jax.named_scope("gopher.route")
         def phys(x):
             if self.backend == "local":
                 return msg.route_local(x)
@@ -497,6 +518,7 @@ class GopherEngine:
             comb = functools.partial(msg.combine_inbox_gather_batched,
                                      v_max=v_max, cap=cap, combine=combine)
 
+        @jax.named_scope("gopher.deliver")
         def finish(iv):
             return jax.vmap(comb)(iv, gb["ib_lo"], gb["ib_hub_idx"],
                                   gb["ib_hub"])
@@ -513,6 +535,7 @@ class GopherEngine:
                 else msg.build_outbox_gather_batched,
                 num_parts=num_parts, cap=cap, combine=combine)
 
+            @jax.named_scope("gopher.pack")
             def pack(state):
                 vals, send, nsent = send_messages(state)
                 slot_vals = jax.vmap(build)(vals, send, gb["ob_inv"])
@@ -533,6 +556,7 @@ class GopherEngine:
                 msg.unpack_slots if Q is None
                 else msg.unpack_slots_batched, combine=combine)
 
+            @jax.named_scope("gopher.pack")
             def pack(state):
                 vals, send, nsent = send_messages(state)
                 pvals, pinv, counts = jax.vmap(build)(vals, send,
@@ -548,7 +572,8 @@ class GopherEngine:
 
             def route(payload):
                 pvals, pinv = payload
-                iv = jax.vmap(unpack)(phys(pvals), phys(pinv))
+                with jax.named_scope("gopher.route"):
+                    iv = jax.vmap(unpack)(phys(pvals), phys(pinv))
                 return finish(iv), {}
 
         else:  # tiered / phased
@@ -559,6 +584,7 @@ class GopherEngine:
                 num_parts=num_parts, cap=cap, combine=combine)
             Qg = 1 if Q is None else Q
 
+            @jax.named_scope("gopher.pack")
             def pack(state):
                 vals, send, nsent = send_messages(state)
                 slot_vals = jax.vmap(build)(vals, send, gb["ob_inv"])
@@ -590,6 +616,7 @@ class GopherEngine:
                 sv4, pvals, sids, over = payload
                 v_local = sv4.shape[0]
 
+                @jax.named_scope("gopher.route")
                 def tier_route(sv4):
                     return msg.route_tiered(
                         sv4, pvals.reshape(v_local, num_parts, cap, Qg),
@@ -600,26 +627,54 @@ class GopherEngine:
                     iv4 = tier_route(sv4)
                     rex = {}
                 else:  # phased: per-superstep dense retry on overflow
-                    over_any = jnp.any(over > 0).astype(jnp.int32)
-                    if axis is not None and D > 1:
-                        over_any = jax.lax.psum(over_any, axis)
-                    retry = over_any > 0
+                    with jax.named_scope("gopher.route"):
+                        over_any = jnp.any(over > 0).astype(jnp.int32)
+                        if axis is not None and D > 1:
+                            over_any = jax.lax.psum(over_any, axis)
+                        retry = over_any > 0
 
-                    def dense_route(sv4):
-                        flat = phys(sv4.reshape(v_local, num_parts,
-                                                cap * Qg))
-                        return flat.reshape(v_local, num_parts, cap, Qg)
+                        def dense_route(sv4):
+                            flat = phys(sv4.reshape(v_local, num_parts,
+                                                    cap * Qg))
+                            return flat.reshape(v_local, num_parts, cap, Qg)
 
-                    iv4 = jax.lax.cond(retry, dense_route, tier_route, sv4)
-                    rex = {"wire": jnp.where(
-                               retry, jnp.int32(v_local * num_parts * cap),
-                               jnp.int32(sched.device_round_slots())),
-                           "dstep": retry.astype(jnp.int32)}
+                        iv4 = jax.lax.cond(retry, dense_route, tier_route,
+                                           sv4)
+                        rex = {"wire": jnp.where(
+                                   retry, jnp.int32(v_local * num_parts * cap),
+                                   jnp.int32(sched.device_round_slots())),
+                               "dstep": retry.astype(jnp.int32)}
                 iv = iv4.reshape(v_local, num_parts,
                                  cap if Q is None else cap * Qg)
                 return finish(iv), rex
 
         return pack, route
+
+    def _reduce_stats(self, scalars, liters, changed_q=None):
+        """The staged superstep's fused stats reduction: ``scalars`` (int32
+        counters), this device's busiest partition's sweeps ``m_d`` in its
+        own slot of a length-D vector (D devices on the mesh axis, 1 on
+        'local'), and ``changed_q`` (per-query flags, or None), in ONE psum
+        on shard_map. Returns (the summed scalars, the superstep's lockstep
+        sweeps M = max_d m_d, its chip wait Σ_d (M − m_d), the summed
+        ``changed_q``). Every device reads every m_d, so the counters cost
+        no collective of their own."""
+        m = jnp.max(liters).astype(jnp.int32)
+        if self.backend == "shard_map":
+            mvec = jnp.zeros((int(self.mesh.shape[self.axis_name]),),
+                             jnp.int32).at[
+                jax.lax.axis_index(self.axis_name)].set(m)
+        else:
+            mvec = m[None]
+        n, d = len(scalars), mvec.shape[0]
+        stats = jnp.concatenate([jnp.stack(scalars), mvec]
+                                + ([] if changed_q is None else [changed_q]))
+        if self.backend == "shard_map":
+            stats = jax.lax.psum(stats, self.axis_name)
+        mvec = stats[n:n + d]
+        lock = jnp.max(mvec)
+        return (tuple(stats[i] for i in range(n)), lock, jnp.sum(lock - mvec),
+                None if changed_q is None else stats[n + d:])
 
     def _run_batched(self, gb, num_queries: Optional[int] = None):
         """The full BSP loop over a partition batch. Runs as-is on the local
@@ -655,7 +710,8 @@ class GopherEngine:
                      hist=jnp.zeros((self.max_supersteps,), jnp.int32),
                      whist=jnp.zeros((self.max_supersteps + 1,),
                                      jnp.int32).at[0].set(wire0),
-                     sent=nsent0, wire=wire0)
+                     sent=nsent0, wire=wire0,
+                     lsweeps=jnp.int32(0), cwait=jnp.int32(0))
         if mode in ("compact", "tiered"):
             # per-round Σ packed counts — the frontier-width histogram
             # the changed-profile EWMA (Gopher Phases) learns from
@@ -678,42 +734,39 @@ class GopherEngine:
                                                                    inbox, step)
             # the halt vote rides the same reduction as the wire counters:
             # ONE fused psum per superstep carries [pairs-changed?, nsent,
-            # wire, counts(, per-query changed)] — the count vector the
-            # compact exchange produces anyway — instead of a separate
-            # all-reduce round per counter.
-            cnt = (jnp.sum(ex["pairs"]).astype(jnp.int32)
-                   if "pairs" in ex else jnp.int32(0))
-            if Q is None:
-                nchanged = jnp.sum(changed.astype(jnp.int32))
-                stats = jnp.stack([nchanged, nsent, wire, cnt])
-                if self.backend == "shard_map":
-                    stats = jax.lax.psum(stats, self.axis_name)
-                nchanged, nsent, wire, cnt = (stats[0], stats[1], stats[2],
-                                              stats[3])
-                any_changed = nchanged > 0
-            else:
-                changed_q = jnp.any(changed, axis=0).astype(jnp.int32)  # (Q,)
-                nchanged = jnp.sum(jnp.any(changed, axis=-1).astype(jnp.int32))
-                stats = jnp.concatenate(
-                    [jnp.stack([nchanged, nsent, wire, cnt]), changed_q])
-                if self.backend == "shard_map":
-                    stats = jax.lax.psum(stats, self.axis_name)
-                nchanged, nsent, wire, cnt = (stats[0], stats[1], stats[2],
-                                              stats[3])
-                changed_q = stats[4:]
-                any_changed = jnp.any(changed_q > 0)
-            new_tele = dict(liters=tele["liters"] + liters,
-                            hist=tele["hist"].at[step].set(nchanged),
-                            whist=tele["whist"].at[step + 1].set(wire),
-                            sent=tele["sent"] + nsent,
-                            wire=tele["wire"] + wire)
-            if "chist" in tele:
-                new_tele["chist"] = tele["chist"].at[step + 1].set(cnt)
-            for k, v in ex.items():
-                new_tele[k] = tele[k] + v
-            if Q is not None:
-                new_tele["qsteps"] = jnp.where(changed_q > 0, step + 1,
-                                               tele["qsteps"])
+            # wire, counts, each device's busiest partition's sweeps(,
+            # per-query changed)] — the count vector the compact exchange
+            # produces anyway — instead of a separate all-reduce round per
+            # counter.
+            with jax.named_scope("gopher.stats"):
+                cnt = (jnp.sum(ex["pairs"]).astype(jnp.int32)
+                       if "pairs" in ex else jnp.int32(0))
+                changed_q = None
+                if Q is None:
+                    nchanged = jnp.sum(changed.astype(jnp.int32))
+                else:
+                    changed_q = jnp.any(changed, axis=0).astype(jnp.int32)
+                    nchanged = jnp.sum(jnp.any(changed,
+                                               axis=-1).astype(jnp.int32))
+                (nchanged, nsent, wire, cnt), lock, wait, changed_q = \
+                    self._reduce_stats([nchanged, nsent, wire, cnt], liters,
+                                       changed_q)
+                any_changed = (nchanged > 0 if Q is None
+                               else jnp.any(changed_q > 0))
+                new_tele = dict(liters=tele["liters"] + liters,
+                                hist=tele["hist"].at[step].set(nchanged),
+                                whist=tele["whist"].at[step + 1].set(wire),
+                                sent=tele["sent"] + nsent,
+                                wire=tele["wire"] + wire,
+                                lsweeps=tele["lsweeps"] + lock,
+                                cwait=tele["cwait"] + wait)
+                if "chist" in tele:
+                    new_tele["chist"] = tele["chist"].at[step + 1].set(cnt)
+                for k, v in ex.items():
+                    new_tele[k] = tele[k] + v
+                if Q is not None:
+                    new_tele["qsteps"] = jnp.where(changed_q > 0, step + 1,
+                                                   tele["qsteps"])
             return state, inbox, step + 1, ~any_changed, new_tele
 
         state, _, steps, _, tele = jax.lax.while_loop(
@@ -1005,7 +1058,8 @@ class GopherEngine:
             over=jnp.zeros((p_local, K, num_parts), jnp.int32
                            ).at[:, 0].add(ex0["over"]),
             dsteps=ex0["dstep"],
-            seg_end=jnp.zeros((K,), jnp.int32))
+            seg_end=jnp.zeros((K,), jnp.int32),
+            lsweeps=jnp.int32(0), cwait=jnp.int32(0))
         if Q is not None:
             tele0["qsteps"] = jnp.zeros((Q,), jnp.int32)
 
@@ -1029,58 +1083,55 @@ class GopherEngine:
                 state, inbox, step, _, streak, tele = c
                 state, inbox, changed, liters, nsent, wire, ex = _sstep(
                     state, inbox, step)
-                cnt = jnp.sum(ex["pairs"]).astype(jnp.int32)
-                if _nlim is None:
-                    viol = jnp.int32(0)
-                else:
-                    nl = jnp.asarray(_nlim)
-                    v_local = ex["pairs"].shape[0]
-                    if self.backend == "shard_map" and p_local < num_parts:
-                        nl = jax.lax.dynamic_slice(
-                            nl, (jax.lax.axis_index(self.axis_name)
-                                 * v_local, 0), (v_local, num_parts))
+                with jax.named_scope("gopher.stats"):
+                    cnt = jnp.sum(ex["pairs"]).astype(jnp.int32)
+                    if _nlim is None:
+                        viol = jnp.int32(0)
                     else:
-                        nl = nl[:v_local]
-                    viol = jnp.sum((ex["pairs"] > nl).astype(jnp.int32))
-                if Q is None:
-                    nchanged = jnp.sum(changed.astype(jnp.int32))
-                    stats = jnp.stack([nchanged, nsent, wire, cnt, viol])
-                    if self.backend == "shard_map":
-                        stats = jax.lax.psum(stats, self.axis_name)
-                    nchanged, nsent, wire, cnt, viol = (
-                        stats[0], stats[1], stats[2], stats[3], stats[4])
-                    any_changed = nchanged > 0
-                else:
-                    changed_q = jnp.any(changed, axis=0).astype(jnp.int32)
-                    nchanged = jnp.sum(jnp.any(changed,
-                                               axis=-1).astype(jnp.int32))
-                    stats = jnp.concatenate(
-                        [jnp.stack([nchanged, nsent, wire, cnt, viol]),
-                         changed_q])
-                    if self.backend == "shard_map":
-                        stats = jax.lax.psum(stats, self.axis_name)
-                    nchanged, nsent, wire, cnt, viol = (
-                        stats[0], stats[1], stats[2], stats[3], stats[4])
-                    changed_q = stats[5:]
-                    any_changed = jnp.any(changed_q > 0)
-                # demotion streak: a dense-retried superstep's counts are
-                # real demand, so they participate like any other round
-                streak = jnp.where(viol == 0, streak + 1, jnp.int32(0))
-                new_tele = dict(
-                    liters=tele["liters"] + liters,
-                    hist=tele["hist"].at[step].set(nchanged),
-                    whist=tele["whist"].at[step + 1].set(wire),
-                    chist=tele["chist"].at[step + 1].set(cnt),
-                    phist=tele["phist"].at[step + 1].set(_k),
-                    sent=tele["sent"] + nsent,
-                    wire=tele["wire"] + wire,
-                    pairs=tele["pairs"].at[:, _k].add(ex["pairs"]),
-                    over=tele["over"].at[:, _k].add(ex["over"]),
-                    dsteps=tele["dsteps"] + ex["dstep"],
-                    seg_end=tele["seg_end"])
-                if Q is not None:
-                    new_tele["qsteps"] = jnp.where(changed_q > 0, step + 1,
-                                                   tele["qsteps"])
+                        nl = jnp.asarray(_nlim)
+                        v_local = ex["pairs"].shape[0]
+                        if self.backend == "shard_map" and p_local < num_parts:
+                            nl = jax.lax.dynamic_slice(
+                                nl, (jax.lax.axis_index(self.axis_name)
+                                     * v_local, 0), (v_local, num_parts))
+                        else:
+                            nl = nl[:v_local]
+                        viol = jnp.sum((ex["pairs"] > nl).astype(jnp.int32))
+                    changed_q = None
+                    if Q is None:
+                        nchanged = jnp.sum(changed.astype(jnp.int32))
+                    else:
+                        changed_q = jnp.any(changed, axis=0).astype(jnp.int32)
+                        nchanged = jnp.sum(jnp.any(changed,
+                                                   axis=-1).astype(jnp.int32))
+                    (nchanged, nsent, wire, cnt, viol), lock, wait, \
+                        changed_q = self._reduce_stats(
+                            [nchanged, nsent, wire, cnt, viol], liters,
+                            changed_q)
+                    any_changed = (nchanged > 0 if Q is None
+                                   else jnp.any(changed_q > 0))
+                    # demotion streak: a dense-retried superstep's counts
+                    # are real demand, so they participate like any other
+                    # round
+                    streak = jnp.where(viol == 0, streak + 1, jnp.int32(0))
+                    new_tele = dict(
+                        liters=tele["liters"] + liters,
+                        hist=tele["hist"].at[step].set(nchanged),
+                        whist=tele["whist"].at[step + 1].set(wire),
+                        chist=tele["chist"].at[step + 1].set(cnt),
+                        phist=tele["phist"].at[step + 1].set(_k),
+                        sent=tele["sent"] + nsent,
+                        wire=tele["wire"] + wire,
+                        pairs=tele["pairs"].at[:, _k].add(ex["pairs"]),
+                        over=tele["over"].at[:, _k].add(ex["over"]),
+                        dsteps=tele["dsteps"] + ex["dstep"],
+                        seg_end=tele["seg_end"],
+                        lsweeps=tele["lsweeps"] + lock,
+                        cwait=tele["cwait"] + wait)
+                    if Q is not None:
+                        new_tele["qsteps"] = jnp.where(changed_q > 0,
+                                                       step + 1,
+                                                       tele["qsteps"])
                 return state, inbox, step + 1, ~any_changed, streak, new_tele
 
             state, inbox, step, done, streak, tele = jax.lax.while_loop(
@@ -1248,6 +1299,9 @@ class GopherEngine:
         m.histogram("engine_run_supersteps", lab).observe(t.supersteps)
         if t.lockstep_sweeps is not None:
             m.histogram("engine_lockstep_sweeps").observe(t.lockstep_sweeps)
+        if t.chip_wait_sweeps is not None:
+            m.histogram("engine_chip_wait_sweeps").observe(
+                t.chip_wait_sweeps)
         m.gauge("engine_partition_imbalance", lab).set(
             obs_skew.imbalance_score(t.local_iters))
 
@@ -1385,7 +1439,7 @@ class GopherEngine:
         over_acc = np.zeros_like(pairs_acc)
         seg_end = np.zeros(K, np.int64)
         qsteps = np.zeros(Q, np.int64) if Q is not None else None
-        sent = wire_total = dsteps = 0
+        sent = wire_total = dsteps = lsweeps = cwait = 0
         psec = np.zeros(num_parts, np.float64)
         part_verts = tuple(int(x) for x in
                            np.asarray(self.pg.vmask, bool).sum(1))
@@ -1486,6 +1540,11 @@ class GopherEngine:
                             if 0 <= p < num_parts:
                                 psec[p] += s
                         liters += li_np
+                        # the fused loop's lockstep and chip-wait counts:
+                        # device d holds the d-th contiguous run of parts
+                        m_d = li_np.reshape(nd, -1).max(axis=1)
+                        lsweeps += int(m_d.max())
+                        cwait += int((m_d.max() - m_d).sum())
                         hist[step] = nchanged
                         whist[step + 1] = wire_i
                         sent += nsent_i
@@ -1503,7 +1562,8 @@ class GopherEngine:
             seg_end[k] = step
 
         tele = dict(liters=liters, hist=hist, whist=whist,
-                    sent=sent, wire=wire_total, psec=psec)
+                    sent=sent, wire=wire_total, psec=psec, lsweeps=lsweeps,
+                    cwait=cwait)
         if mode in ("compact", "tiered", "phased"):
             tele["chist"] = chist
             tele["pairs"] = pairs_acc
@@ -1766,6 +1826,8 @@ class GopherEngine:
             t.part_seconds = np.asarray(tele["psec"], np.float64).reshape(-1)
         if "lsweeps" in tele:
             t.lockstep_sweeps = int(tele["lsweeps"])
+        if "cwait" in tele:
+            t.chip_wait_sweeps = int(tele["cwait"])
         if phased:
             # phase buckets travel parts-leading (P, K, P); report (K, P, P)
             by_phase = np.transpose(pair_slots, (1, 0, 2))
@@ -2035,7 +2097,8 @@ class GopherEngine:
         state_spec = jax.tree.map(lambda _: spec,
                                   jax.eval_shape(lambda g: jax.vmap(self.program.init)(g),
                                                  gb_shapes))
-        tele_spec = dict(liters=spec, hist=rep, whist=rep, sent=rep, wire=rep)
+        tele_spec = dict(liters=spec, hist=rep, whist=rep, sent=rep, wire=rep,
+                         lsweeps=rep, cwait=rep)
         # per-pair wire telemetry shards over parts like liters: each
         # device owns its local source rows of the (P, P) matrices (phased:
         # of the (P, K, P) per-phase buckets)

@@ -120,26 +120,29 @@ class SemiringProgram:
         # ZERO sweeps this superstep.
         f0 = state["frontier"] | improved
         max_it = self.max_local_iters
-        if max_it == 1:
-            # vertex-centric baseline (Giraph): one full sweep, unmasked
-            x2 = self._sweep(x, gb)
-            iters = jnp.int32(1)
-            f_left = jnp.zeros_like(vmask)
-        else:
-            cap = jnp.int32(max_it if max_it is not None else 2**30)
+        # the local fixpoint is the engine's gopher.sweep stage
+        # (repro.obs.op_stages reads it back per instruction)
+        with jax.named_scope("gopher.sweep"):
+            if max_it == 1:
+                # vertex-centric baseline (Giraph): one full sweep, unmasked
+                x2 = self._sweep(x, gb)
+                iters = jnp.int32(1)
+                f_left = jnp.zeros_like(vmask)
+            else:
+                cap = jnp.int32(max_it if max_it is not None else 2**30)
 
-            def cond(c):
-                _, f, it = c
-                return jnp.any(f) & (it < cap)
+                def cond(c):
+                    _, f, it = c
+                    return jnp.any(f) & (it < cap)
 
-            def body(c):
-                xc, f, it = c
-                for _ in range(self.fixpoint_unroll):
-                    xc, f = self._masked_sweep(xc, f, gb)
-                return xc, f, it + self.fixpoint_unroll
+                def body(c):
+                    xc, f, it = c
+                    for _ in range(self.fixpoint_unroll):
+                        xc, f = self._masked_sweep(xc, f, gb)
+                    return xc, f, it + self.fixpoint_unroll
 
-            x2, f_left, iters = jax.lax.while_loop(cond, body,
-                                                   (x, f0, jnp.int32(0)))
+                x2, f_left, iters = jax.lax.while_loop(
+                    cond, body, (x, f0, jnp.int32(0)))
         # the send set: vertices with news this superstep. The SEED frontier
         # needs no step-0 override here — the engine PRIMES the first inbox
         # from the init state's messages (gated on init's changed_v = seed),
@@ -211,8 +214,9 @@ class PageRankProgram:
         vmask = gb["vmask"]
         r = state["r"]
         ones = jnp.ones_like(gb["wgt"])
-        pull = ops.semiring_spmv(self._contrib(r, gb), gb["nbr"], ones,
-                                 "plus_times", backend=self.spmv_backend)
+        with jax.named_scope("gopher.sweep"):
+            pull = ops.semiring_spmv(self._contrib(r, gb), gb["nbr"], ones,
+                                     "plus_times", backend=self.spmv_backend)
         tele = (self.teleport_fn(gb) if self.teleport_fn is not None
                 else 1.0 / self.n_global)
         dangling = jnp.sum(jnp.where(vmask & (gb["out_degree"] == 0), r, 0.0))

@@ -276,17 +276,22 @@ class step:
 _HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)", re.M)
 _HLO_OP = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*?"
                      r"metadata=\{[^}]*?op_name=\"([^\"]*)\"", re.M)
+# a scope traced under a transform reads ``vmap(gopher.sweep)``
+_STAGE = re.compile(r"^(?:\w+\()*(" + re.escape(SPAN_PREFIX) + r"[\w\-]+)\)*$")
 
 
 def hlo_stages(hlo_text: str):
     """``(module name, {instruction: stage})`` of one compiled module's
     HLO text: an instruction's stage is the innermost ``gopher.*``
-    component of its ``op_name`` metadata (a fusion carries its root's);
+    component of its ``op_name`` metadata (a fusion carries its root's),
+    read through the transforms it was traced under (``vmap(gopher.sweep)``,
+    as the staged loops' vmapped partitions name their fixpoint);
     instructions outside every named stage are left out."""
     m = _HLO_MODULE.search(hlo_text)
     stages = {}
     for name, op_name in _HLO_OP.findall(hlo_text):
-        scopes = [c for c in op_name.split("/") if c.startswith(SPAN_PREFIX)]
+        scopes = [s.group(1) for s in map(_STAGE.match, op_name.split("/"))
+                  if s]
         if scopes:
             stages[name] = scopes[-1]
     return (m.group(1) if m else ""), stages

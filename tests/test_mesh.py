@@ -425,6 +425,95 @@ print("OK")
     assert "OK" in out.stdout
 
 
+def test_partitioned_sssp_over_four_devices():
+    """The partitioned deployment: 16 partitions, 4 on each of D=4 CPU
+    devices, through ``repro.algorithms.sssp`` on the shard_map backend
+    (exchange 'auto', so tiered). Distances equal a plain float32 min-plus
+    relaxation to the bit; supersteps, per-partition sweeps and lockstep
+    sweeps equal the one-device megastep route's; the chip wait equals a
+    host recount from each superstep's per-partition sweeps; the compiled
+    loop names all five staged stages."""
+    import subprocess
+    import sys
+    import os
+    prog = r"""
+import numpy as np
+import jax
+from repro import algorithms
+from repro.core import SemiringProgram, compat, engine, make_sssp_init
+from repro.core.blocks import graph_block
+from repro.gofs import bfs_grow_partition, road_grid
+from repro.gofs.formats import partition_graph
+from repro.kernels import megastep as mega
+from repro.obs import op_stages
+
+g = road_grid(24, 24, drop_frac=0.05, seed=3, weighted=True)
+pg = partition_graph(g, bfs_grow_partition(g, 16, seed=0), 16)
+root = 5
+mesh = compat.make_mesh((4,), ("parts",))
+dist, t = algorithms.sssp(pg, root, backend="shard_map", mesh=mesh)
+assert t.exchange == "tiered" and t.spills == 0 and not t.retried
+
+# plain float32 min-plus relaxation over the in-edge lists, to quiescence
+dst = np.repeat(np.arange(g.n), np.diff(g.indptr))
+ref = np.full(g.n, np.inf, np.float32)
+ref[root] = 0.0
+while True:
+    nxt = ref.copy()
+    np.minimum.at(nxt, dst, ref[g.indices] + g.weights.astype(np.float32))
+    if np.array_equal(nxt, ref):
+        break
+    ref = nxt
+got = np.full(g.n, np.nan, np.float32)
+got[pg.global_id[pg.vmask]] = dist[pg.vmask]
+assert np.array_equal(got, ref)
+
+# the one-device megastep route runs the same supersteps and sweeps
+_, t1 = algorithms.sssp(pg, root)
+assert t1.exchange == "megastep"
+assert t.supersteps == t1.supersteps
+assert np.array_equal(t.local_iters, t1.local_iters)
+assert t.lockstep_sweeps == t1.lockstep_sweeps
+
+# chip wait recounted on the host: each superstep's per-partition sweeps,
+# device d holding partitions 4d .. 4d + 3
+p = SemiringProgram(semiring="min_plus",
+                    init_fn=make_sssp_init(int(pg.part_of[root]),
+                                           int(pg.local_of[root])))
+gb = graph_block(pg)
+cm = mega.compose_mailbox(gb)
+st = jax.vmap(p.init)(gb)
+x, ch, fr = (st[k].reshape(-1) for k in ("x", "changed_v", "frontier"))
+step = jax.jit(lambda x, ch, fr: mega.megastep_semiring(
+    x, ch, fr, cm, "min_plus")[:4])
+lock = wait = 0
+while True:
+    x, ch, fr, li = step(x, ch, fr)
+    m = np.asarray(li).reshape(4, 4).max(axis=1)
+    lock += int(m.max())
+    wait += int((m.max() - m).sum())
+    if not np.asarray(ch).any():
+        break
+assert (lock, wait) == (t.lockstep_sweeps, t.chip_wait_sweeps)
+assert wait > 0
+
+loops = {k: v for k, v in engine._RUNNER_CACHE.items() if k[2] == "tiered"}
+names = set(op_stages(loops)["jit_gopher_tiered"].values())
+assert names == {"gopher.sweep", "gopher.pack", "gopher.route",
+                 "gopher.deliver", "gopher.stats"}, names
+print("OK")
+"""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4").strip()
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in ("src", env.get("PYTHONPATH", "")) if p)
+    out = subprocess.run([sys.executable, "-c", prog], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "OK" in out.stdout
+
+
 # ---------------- traffic profile ----------------
 
 def test_profile_update_and_announce(road):

@@ -210,6 +210,8 @@ def test_traced_run_bit_identical(road, exchange):
     assert t0.wire_slots == t1.wire_slots
     np.testing.assert_array_equal(t0.wire_hist, t1.wire_hist)
     np.testing.assert_array_equal(t0.local_iters, t1.local_iters)
+    assert t0.lockstep_sweeps == t1.lockstep_sweeps
+    assert t0.chip_wait_sweeps == t1.chip_wait_sweeps
     if t0.count_hist is not None:
         np.testing.assert_array_equal(t0.count_hist, t1.count_hist)
         np.testing.assert_array_equal(t0.pair_slots, t1.pair_slots)
@@ -476,10 +478,29 @@ def test_lockstep_sweeps_count_the_flat_fixpoint(mesh_graph, name):
     assert tt.lockstep_sweeps == t.lockstep_sweeps
 
 
-def test_staged_loops_carry_no_lockstep_count(mesh_graph):
+def test_staged_loop_counts_lockstep_sweeps_and_no_chip_wait(mesh_graph):
+    """On one device the staged loop's vmapped fixpoints run as long as
+    the busiest partition's, the megastep's definition; no chip waits."""
     _, t = GopherEngine(mesh_graph, _prog(mesh_graph, "sssp"),
                         exchange="dense").run()
-    assert t.lockstep_sweeps is None
+    assert (t.supersteps, t.lockstep_sweeps) == _host_lockstep(mesh_graph)
+    assert t.chip_wait_sweeps == 0
+
+
+@pytest.mark.parametrize("exchange", ("dense", "tiered", "phased"))
+def test_op_stages_name_the_staged_loops_stages(mesh_graph, exchange):
+    from repro.core import engine
+    from repro.obs import op_stages
+    prog = _prog(mesh_graph, "sssp")
+    GopherEngine(mesh_graph, prog, exchange=exchange,
+                 tier_plan=_plan(mesh_graph, exchange)).run()
+    loops = {k: v for k, v in engine._RUNNER_CACHE.items()
+             if k[0] is prog and k[2] == exchange}
+    stages = op_stages(loops)
+    assert set(stages) == {f"jit_gopher_{exchange}"}
+    assert set(stages[f"jit_gopher_{exchange}"].values()) == {
+        "gopher.sweep", "gopher.pack", "gopher.route", "gopher.deliver",
+        "gopher.stats"}
 
 
 @pytest.mark.parametrize("name", ANALYTICS)
