@@ -44,17 +44,18 @@ class SemiringProgram:
     fixpoint is a *masked* sweep gated on it. A partition whose frontier is
     empty runs ZERO sweep iterations that superstep (its while-loop condition
     is false on entry) instead of recomputing everything to discover nothing
-    changed; within an active partition, rows with no active in-neighbor cost
-    ~0 (kernels.semiring_spmv_frontier). For idempotent ⊕ the masked fixpoint
-    is bitwise identical to the unmasked one.
+    changed; within an active partition, a lane whose source is settled
+    contributes the ⊕-identity. For idempotent ⊕ the masked fixpoint is
+    bitwise identical to the unmasked one.
 
     The frontier keeps an invariant: every vertex outside it has already
     relaxed all its local out-neighbours. Each seed meets it (all of
     ``vmask``; a resume's seed from algorithms.incremental.resume_seed),
     delivery adds every vertex the inbox lowered, and each sweep adds every
-    vertex it changed. The fused route's sweep relies on it: it gathers
-    only the frontier's values, ⊕-identity elsewhere (kernels.megastep.
-    sweep_flat), so a hand-made resume seed must cover the local
+    vertex it changed. Both routes' sweeps rely on it: the fused route's
+    (kernels.megastep.sweep_flat) and, on the jnp backend, the staged
+    superstep's (:meth:`_masked_sweep`) gather only the frontier's values,
+    ⊕-identity elsewhere, so a hand-made resume seed must cover the local
     in-neighbours of every vertex whose value it raised.
 
     ``resume=True`` starts from a previous fixpoint: ``gb["x0"]`` is the prior
@@ -100,12 +101,27 @@ class SemiringProgram:
         return _ew_combine(self.combine, x, y)
 
     def _masked_sweep(self, x, f, gb):
-        """One frontier-masked relaxation: recompute only rows with an active
-        in-neighbor; the next frontier is the rows that actually changed."""
-        y, _ = ops.semiring_spmv_frontier(x, f, gb["nbr"], gb["wgt"],
-                                          self.semiring,
-                                          backend=self.spmv_backend,
-                                          interpret=self.interpret)
+        """One frontier-masked relaxation; the next frontier is the rows
+        that actually changed.
+
+        On the ``jnp`` backend it gathers one array: the state with the
+        ⊕-identity off the frontier, ``where(f, x, ident)``, reduced by the
+        plain ELL sweep. Under the frontier invariant (class docstring) a
+        lane whose source is settled could not move its row, so after the
+        ⊕ with the state this equals the sweep that gathers the state and
+        the frontier flags apart, bit for bit, with no flag gather. The
+        ``pallas`` backend keeps ops.semiring_spmv_frontier, whose row-block
+        skip reads the flags."""
+        if self.spmv_backend == "pallas":
+            y, _ = ops.semiring_spmv_frontier(x, f, gb["nbr"], gb["wgt"],
+                                              self.semiring,
+                                              backend=self.spmv_backend,
+                                              interpret=self.interpret)
+        else:
+            ident = jnp.inf if self.combine == "min" else -jnp.inf
+            y = ops.semiring_spmv(jnp.where(f, x, ident), gb["nbr"],
+                                  gb["wgt"], self.semiring,
+                                  backend=self.spmv_backend)
         x2 = _ew_combine(self.combine, x, y)
         return x2, (x2 != x) & gb["vmask"]
 

@@ -45,7 +45,13 @@ def semiring_spmv_frontier(x: jnp.ndarray, frontier: jnp.ndarray,
                            interpret: bool = False):
     """Frontier-masked ELL sweep (idempotent ⊕ only): rows with no active
     in-neighbor yield the identity at ~0 cost (the Pallas path predicates the
-    gather+combine per row block on the frontier). Returns (y, row_active)."""
+    gather+combine per row block on the frontier). Returns (y, row_active).
+
+    The programs call it only on the Pallas route
+    (``SemiringProgram(spmv_backend="pallas")``); their jnp route gathers
+    the frontier's values alone (core.programs.SemiringProgram.
+    _masked_sweep). The jnp path here remains the Pallas route's
+    reference."""
     if backend == "jnp":
         return semiring_spmv_frontier_ref(x, frontier, nbr, wgt, semiring)
     if backend == "pallas":

@@ -123,6 +123,12 @@ def semiring_spmv_frontier_ref(x: jnp.ndarray, frontier: jnp.ndarray,
 
     frontier: (V,) bool. Returns (y, row_active) — row_active is the next
     sweep's candidate set before the caller intersects it with "changed".
+
+    It gathers the state and the frontier flags apart. It remains the
+    reference of the Pallas kernel that the programs call on the Pallas
+    route (ops.semiring_spmv_frontier); their jnp sweeps gather the
+    frontier's values alone (kernels.megastep.sweep_flat,
+    core.programs.SemiringProgram._masked_sweep).
     """
     assert semiring in ("min_plus", "max_first"), \
         "frontier masking requires an idempotent ⊕ (min/max)"

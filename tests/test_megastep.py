@@ -24,14 +24,17 @@ from repro.core import (GopherEngine, PageRankProgram, PhasedTierPlan,
 from repro.algorithms.incremental import _boundary_sources, resume_seed
 from repro.gofs import EdgeDelta, apply_delta, bfs_grow_partition, road_grid
 from repro.gofs.formats import PAD, partition_graph
-from repro.kernels import megastep as mega
+from repro.kernels import megastep as mega, ops
+
+
+def _road(parts=4):
+    g = road_grid(10, 11, drop_frac=0.06, seed=3, weighted=True)
+    return g, partition_graph(g, bfs_grow_partition(g, parts, seed=0), parts)
 
 
 @pytest.fixture(scope="module")
 def road():
-    g = road_grid(10, 11, drop_frac=0.06, seed=3, weighted=True)
-    pg = partition_graph(g, bfs_grow_partition(g, 4, seed=0), 4)
-    return g, pg
+    return _road()
 
 
 def _source(pg):
@@ -309,7 +312,14 @@ def _megastep_case(case, road):
         seed = np.broadcast_to(pg.vmask.reshape(-1, 1), x.shape)
         cm = mega.compose_mailbox(graph_block(pg), adjacency="binned")
         return cm, "min_plus", 2, jnp.asarray(x), seed, seed
-    # resumes: the previous fixpoint of pg, restarted on the delta's graph
+    pg2, prog, x0, fr = _resume(case, g, pg)
+    return (mega.compose_mailbox(graph_block(pg2)), prog.semiring, 1,
+            jnp.asarray(x0).reshape(-1), fr.reshape(-1), fr.reshape(-1))
+
+
+def _resume(case, g, pg):
+    """(pg2, prog, x0, frontier0): the previous fixpoint of ``pg``'s cold
+    program, seeded for a restart on the delta's graph ``pg2``."""
     rng = np.random.default_rng(4)
     a = g.csr().tocoo()
     if case == "sssp_insert":
@@ -327,11 +337,9 @@ def _megastep_case(case, road):
     res = apply_delta(pg, delta, directed=False)
     assert res.dirty_remove.any() == (case == "cc_delete")
     prev, _ = GopherEngine(pg, prog, exchange="dense").run()
-    gb = graph_block(res.pg)
-    init = np.asarray(jax.vmap(prog.init)(gb)["x"])
+    init = np.asarray(jax.vmap(prog.init)(graph_block(res.pg))["x"])
     x0, fr = resume_seed(res.pg, np.asarray(prev["x"]), res, init)
-    return (mega.compose_mailbox(gb), prog.semiring, 1,
-            jnp.asarray(x0).reshape(-1), fr.reshape(-1), fr.reshape(-1))
+    return res.pg, prog, x0, fr
 
 
 @pytest.mark.parametrize("case", ["sssp_cold", "cc_cold", "sssp_insert",
@@ -413,6 +421,137 @@ def test_fixpoint_sweep_gathers_the_neighbour_table_once(road):
 
     assert gathers(mega.megastep_semiring) == 1
     assert gathers(_two_gather_megastep) == 2   # the count sees both
+
+
+# ---------------- one gather per staged sweep ----------------
+
+class _TwoGatherProgram(SemiringProgram):
+    """The program with the staged sweep that gathers the state and the
+    frontier flags apart (ops.semiring_spmv_frontier on the jnp backend)."""
+
+    def _masked_sweep(self, x, f, gb):
+        y, _ = ops.semiring_spmv_frontier(x, f, gb["nbr"], gb["wgt"],
+                                          self.semiring)
+        x2 = jnp.minimum(x, y) if self.combine == "min" else jnp.maximum(x, y)
+        return x2, (x2 != x) & gb["vmask"]
+
+
+def _two_gather(prog):
+    return _TwoGatherProgram(**{f.name: getattr(prog, f.name)
+                                for f in dataclasses.fields(prog)})
+
+
+def test_staged_fixpoint_gathers_the_neighbour_table_once(road):
+    """Compiled on the CPU, the staged superstep's fixpoint loop (the
+    vmapped SemiringProgram.superstep that every shard_map run takes)
+    gathers over the (P, v_max, D) neighbour table once per sweep on the
+    jnp backend; the sweep that gathers the flags too counts 2."""
+    _, pg = road
+    gb = graph_block(pg)
+    prog = _programs(pg)["sssp"]
+    st = jax.vmap(prog.init)(gb)
+
+    def gathers(p):
+        step = jax.jit(jax.vmap(lambda s, i, g: p.superstep(s, i, g, 0)))
+        hlo = step.lower(st, st["x"], gb).compile().as_text()
+        return _nbr_table_gathers(hlo, gb["nbr"].size)
+
+    assert gathers(prog) == 1
+    assert gathers(_two_gather(prog)) == 2      # the count sees both
+
+
+def _relax_ref(pg, x, semiring):
+    """Plain float32 relaxation to quiescence over every edge of ``pg``,
+    local (ELL in-neighbour rows) and remote, in (P, v_max) slot
+    coordinates, from the state ``x``."""
+    P, V = pg.vmask.shape
+    p, v, j = np.nonzero(pg.nbr != PAD)
+    q, k = np.nonzero(pg.re_src != PAD)
+    src = np.concatenate([p * V + pg.nbr[p, v, j], q * V + pg.re_src[q, k]])
+    dst = np.concatenate([p * V + v,
+                          pg.re_dst_part[q, k] * V + pg.re_dst_local[q, k]])
+    w = np.concatenate([pg.wgt[p, v, j], pg.re_wgt[q, k]]).astype(np.float32)
+    red = np.minimum if semiring == "min_plus" else np.maximum
+    x = np.asarray(x, np.float32).reshape(-1)
+    while True:
+        nxt = x.copy()
+        red.at(nxt, dst, x[src] + w if semiring == "min_plus" else x[src])
+        if np.array_equal(nxt, x):
+            return x.reshape(P, V)
+        x = nxt
+
+
+STAGED_CASES = ("sssp_bounded", "cc_bounded", "sssp_insert", "cc_delete")
+
+
+def check_staged_route(case, g, pg, **engine):
+    """The staged route (``engine``: its backend, mesh and exchange) with
+    the one-gather sweep equals, bit for bit, the same route with the
+    sweep that gathers the flags apart (state, supersteps, per-partition
+    sweeps), the fused megastep route (state; supersteps and sweeps where
+    the fused route runs the same program) and a plain relaxation."""
+    if case.endswith("_cold"):
+        prog = fused = _programs(pg)[case[:-5]]
+        cold, extra = prog, None
+    elif case.endswith("_bounded"):
+        cold = fused = _programs(pg)[case[:-8]]   # no fused bounded route
+        prog, extra = dataclasses.replace(cold, max_local_iters=3), None
+    else:
+        pg, cold, x0, fr = _resume(case, g, pg)
+        prog = fused = dataclasses.replace(cold, resume=True)
+        extra = {"x0": x0, "frontier0": fr}
+    s, t = GopherEngine(pg, prog, **engine).run(extra=extra)
+    s2, t2 = GopherEngine(pg, _two_gather(prog), **engine).run(extra=extra)
+    x = np.asarray(s["x"])
+    assert np.array_equal(x, np.asarray(s2["x"])), case
+    assert t.supersteps == t2.supersteps, case
+    assert np.array_equal(t.local_iters, t2.local_iters), case
+    assert t.local_iters.sum() > 0, case           # the sweeps did work
+    sf, tf = GopherEngine(pg, fused, exchange="megastep").run(extra=extra)
+    assert np.array_equal(x, np.asarray(sf["x"])), case
+    if fused is prog:
+        assert t.supersteps == tf.supersteps, case
+        assert np.array_equal(t.local_iters, tf.local_iters), case
+    init = np.asarray(jax.vmap(cold.init)(graph_block(pg))["x"])
+    assert np.array_equal(x, _relax_ref(pg, init, prog.semiring)), case
+
+
+@pytest.mark.parametrize("case", STAGED_CASES)
+def test_staged_one_gather_sweep_is_bit_equal(case, road):
+    """Bounded fixpoints and incremental resumes on the local backend's
+    staged dense exchange (cold starts: test_fused_bit_identity_and_telemetry)."""
+    check_staged_route(case, *road, exchange="dense")
+
+
+def test_staged_one_gather_sweep_is_bit_equal_over_four_devices():
+    """The same cases and a cold CC on D=4 CPU devices (forced in a
+    subprocess, before jax initializes), 2 partitions per device, over the
+    tiered exchange that the shard_map runs take (cold SSSP there:
+    test_mesh.test_partitioned_sssp_over_four_devices)."""
+    import os
+    import subprocess
+    import sys
+    prog = r"""
+from repro.core import compat
+import test_megastep as tm
+g, pg = tm._road(8)
+mesh = compat.make_mesh((4,), ("parts",))
+for case in ("cc_cold",) + tm.STAGED_CASES:
+    tm.check_staged_route(case, g, pg, backend="shard_map", mesh=mesh,
+                          exchange="tiered")
+print("OK")
+"""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4").strip()
+    here = os.path.dirname(os.path.abspath(__file__))
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(here, os.pardir, "src"), here,
+                    env.get("PYTHONPATH", "")) if p)
+    out = subprocess.run([sys.executable, "-c", prog], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "OK" in out.stdout
 
 
 def test_engine_dispatches_pallas_backend(road):
