@@ -74,12 +74,12 @@ def validate_engine(engine, num_queries=None, gb_example=None):
     return violations
 
 
-def validate_stage_fns(engine, num_queries=None, gb_example=None,
-                       phase=None):
+def validate_stage_fns(engine, num_queries=None, gb_example=None):
     """Pass 1 over the STAGED STEPPED DRIVER's stage programs — the
-    init/sweep/pack/route jits the checkpointed, traced, and recovery
-    paths dispatch per superstep (Gopher Shield replays ride these, so
-    their collectives must verify exactly like the fused loops'). Each
+    init/sweep/pack/route jits a checkpointed or recovery run dispatches
+    per superstep, under the engine's ``stepped_exchange(checkpointed=
+    True)`` (Gopher Shield replays ride these, so their collectives must
+    verify exactly like the fused loops'). Each
     stage is traced with shape-only inputs chained through ``eval_shape``
     (state from init, payload from pack, inbox from route) and walked by
     the Pass 1 verifier. Raises :class:`SentinelError` on error-severity
@@ -87,24 +87,13 @@ def validate_stage_fns(engine, num_queries=None, gb_example=None,
     import jax
     import jax.numpy as jnp
     from repro.core.blocks import graph_block
-    # recovery replays ride the COMPACT staged loop on these configurations
-    # (engine._run_checkpointed drops to it: megastep has no staged
-    # exchange at all, tiered/phased replay equivalent-bits over compact) —
-    # verify the loop that actually runs
-    if engine.exchange in ("megastep", "tiered", "phased"):
-        prev = engine.exchange
-        engine.exchange = "compact"
-        try:
-            return validate_stage_fns(engine, num_queries=num_queries,
-                                      gb_example=gb_example, phase=phase)
-        finally:
-            engine.exchange = prev
     if gb_example is not None:
         gb_shapes = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
                      for k, v in gb_example.items()}
     else:
         gb_shapes = graph_block(engine.pg, as_spec=True)
-    fns = engine._traced_stage_fns(num_queries, phase)
+    fns = engine.stage_fns(num_queries, None,
+                           engine.stepped_exchange(checkpointed=True))
     state_s = jax.eval_shape(fns["init"], gb_shapes)
     pack_s = jax.eval_shape(fns["pack"], gb_shapes, state_s)
     payload_s = pack_s[0]

@@ -45,6 +45,7 @@ Six wire disciplines share both backends (``exchange=``, see make_exchange):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -147,7 +148,7 @@ class Telemetry:
                                                # fell back to the dense route
                                                # after an in-phase overflow
     # Gopher Balance: wall-clock seconds attributed per partition by the
-    # host-stepped drivers (checkpointed/traced loops) — the TIME channel of
+    # host-stepped driver (checkpointed/traced runs) — the TIME channel of
     # the skew report. Injected straggler stalls land on their targeted
     # partition; the remaining superstep time spreads evenly (one host
     # process can't see real per-partition compute splits). None on the
@@ -159,13 +160,13 @@ class Telemetry:
     # lockstep sweeps are its busiest partition's: on the megastep's flat
     # state and on one device's vmapped partitions alike, and across a
     # mesh, where every device waits at the superstep's collectives for
-    # the slowest one. None on the checkpointed driver.
+    # the slowest one.
     lockstep_sweeps: Optional[int] = None
     # the staged loops' sweep slots in which a device had finished its own
     # partitions' fixpoints and waited for the slowest device's: per
     # superstep Σ_d (max_d m_d − m_d), m_d a device's busiest partition's
     # sweeps, summed over supersteps. 0 on one device; None on the
-    # megastep and checkpointed drivers.
+    # megastep route.
     chip_wait_sweeps: Optional[int] = None
 
     @staticmethod
@@ -186,6 +187,105 @@ class Telemetry:
         score off local_iters, wire skew off pair_slots) — see
         repro.obs.skew.skew_report."""
         return obs_skew.skew_report(self)
+
+
+class _StepTele:
+    """The host-stepped driver's telemetry: each round's and superstep's
+    observation record folded on the host into exactly the keys the
+    matching fused loop emits for the exchange (so _finish/_telemetry are
+    shared verbatim), plus the host-only ``psec`` (Telemetry.part_seconds)
+    and ``rounds`` (the exchange rounds this process ran)."""
+
+    def __init__(self, exchange: str, num_parts: int, max_supersteps: int,
+                 num_phases: int, num_queries: Optional[int],
+                 num_devices: int):
+        self.mode = exchange
+        self.num_devices = num_devices
+        self.liters = np.zeros(num_parts, np.int64)
+        self.psec = np.zeros(num_parts, np.float64)
+        self.hist = np.zeros(max_supersteps, np.int64)
+        self.whist, self.chist, self.phist = (
+            np.zeros(max_supersteps + 1, np.int64) for _ in range(3))
+        # per-pair buckets parts-leading (P, K, P), as the phased loop
+        # carries them; K = 1 on every other exchange
+        self.pairs = np.zeros((num_parts, num_phases, num_parts), np.int64)
+        self.over = np.zeros_like(self.pairs)
+        self.seg_end = np.zeros(num_phases, np.int64)
+        self.qsteps = (None if num_queries is None
+                       else np.zeros(num_queries, np.int64))
+        self.sent = self.wire = self.dsteps = self.lsweeps = self.cwait = 0
+
+    def fold_round(self, rnd: int, phase: int, obs: dict):
+        """Exchange round ``rnd`` (0 = the prime, s + 1 = superstep s's)
+        shipped in ``phase``; returns its (wire, nsent) as host ints."""
+        wire, nsent = int(obs.get("wire", 0)), int(obs["nsent"])
+        self.whist[rnd] = wire
+        self.phist[rnd] = phase
+        self.wire += wire
+        self.sent += nsent
+        if "pairs" in obs:
+            pairs = np.asarray(obs["pairs"], np.int64)
+            self.chist[rnd] = pairs.sum()
+            self.pairs[:, phase] += pairs
+        if "over" in obs:
+            self.over[:, phase] += np.asarray(obs["over"], np.int64)
+        self.dsteps += int(obs.get("dstep", 0))
+        return wire, nsent
+
+    def fold_step(self, step: int, obs: dict):
+        """Superstep ``step``'s sweeps and the host halt vote over its
+        changed flags; returns (#partitions changed, any change)."""
+        li = np.asarray(obs["li"], np.int64)
+        self.liters += li
+        if "sweeps" in obs:          # the flat fixpoint counts its own
+            self.lsweeps += int(obs["sweeps"])
+        else:
+            # the busiest partition's sweeps per device (device d holds
+            # the d-th contiguous run of partitions); every device waits
+            # for the slowest at the superstep's collectives
+            m_d = li.reshape(self.num_devices, -1).max(axis=1)
+            self.lsweeps += int(m_d.max())
+            self.cwait += int((m_d.max() - m_d).sum())
+        ch = np.asarray(obs["changed"])
+        if self.qsteps is None:
+            nchanged, any_changed = int(ch.sum()), bool(ch.any())
+        else:
+            changed_q = ch.any(axis=0)
+            nchanged = int(ch.any(axis=-1).sum())
+            any_changed = bool(changed_q.any())
+            self.qsteps[changed_q] = step + 1
+        self.hist[step] = nchanged
+        return nchanged, any_changed
+
+    def charge(self, seconds: float, effects: Optional[dict]) -> None:
+        """One superstep's wall time into the partitions' time channel:
+        injected stalls land on their target partition, the rest spreads
+        evenly (one host process can't see real per-partition splits)."""
+        P = len(self.psec)
+        stalls = [(p, s) for p, s in (effects or {}).get("stalls", [])
+                  if 0 <= p < P]
+        self.psec += max(seconds - sum(s for _, s in stalls), 0.0) / P
+        for p, s in stalls:
+            self.psec[p] += s
+
+    def out(self, rounds: int) -> dict:
+        ex = self.mode
+        tele = dict(liters=self.liters, hist=self.hist, whist=self.whist,
+                    sent=self.sent, wire=self.wire, lsweeps=self.lsweeps,
+                    psec=self.psec, rounds=rounds)
+        if ex != "megastep":
+            tele["cwait"] = self.cwait
+        if ex != "dense":
+            tele["chist"] = self.chist
+            tele["pairs"] = self.pairs if ex == "phased" else self.pairs[:, 0]
+        if ex in ("tiered", "phased"):
+            tele["over"] = self.over if ex == "phased" else self.over[:, 0]
+        if ex == "phased":
+            tele.update(phist=self.phist, seg_end=self.seg_end,
+                        dsteps=self.dsteps)
+        if self.qsteps is not None:
+            tele["qsteps"] = self.qsteps
+        return tele
 
 
 class GopherEngine:
@@ -279,7 +379,7 @@ class GopherEngine:
             # Gopher Scope: host-side observability. None defers to the process
             # defaults at run time (so launch/scope can arm a tracer AFTER
             # engines were built). A disabled tracer keeps the compiled fused
-            # loop untouched — the traced stepped driver only replaces it when
+            # loop untouched — the host-stepped driver only replaces it when
             # the tracer is enabled.
             self._tracer = tracer
             self._metrics = metrics
@@ -333,7 +433,7 @@ class GopherEngine:
         launch-overhead budget the megastep exists to reclaim. Python-int
         statics are NOT shipped — _run_megastep re-derives them from shapes.
         Callers that trace with a bare block (sentinel's trace_loop, the
-        traced stepped driver) skip this and compose inline."""
+        host-stepped driver) skip this and compose inline."""
         if self.exchange != "megastep":
             return gb
         if self._mega_cm is None:
@@ -461,15 +561,18 @@ class GopherEngine:
         return exchange
 
     def make_exchange_stages(self, gb, num_queries: Optional[int] = None,
-                             phase: Optional[int] = None):
+                             phase: Optional[int] = None,
+                             exchange: Optional[str] = None):
         """The exchange split at its NETWORK BOUNDARY into two closures —
         ``pack(state) -> (payload, nsent, wire, extras)`` (pure device-local
         message build / frontier compaction; payload is the pytree that
         would cross the wire) and ``route(payload) -> (inbox, route_extras)``
         (the collective transpose plus inbox combine). ``make_exchange``
         composes them, so the compiled fused loop's math is exactly the
-        per-stage math; Gopher Scope's traced stepped driver dispatches the
-        stages individually to clock pack vs. exchange wall-clock.
+        per-stage math; the host-stepped driver dispatches the stages
+        individually (see stage_fns), under ``exchange`` (default: the
+        engine's own) — a checkpointed run's replay exchange, see
+        stepped_exchange.
 
         ``route_extras`` is {} except on 'phased', where the per-superstep
         dense-retry decision lives on the route side: {'wire': the corrected
@@ -487,7 +590,7 @@ class GopherEngine:
         combine = prog.combine
         num_parts = self.pg.num_parts
         Q = num_queries
-        mode = self.exchange
+        mode = exchange or self.exchange
         assert mode != "megastep", \
             "the megastep route has no staged exchange (see _run_megastep)"
 
@@ -1146,7 +1249,7 @@ class GopherEngine:
     def run(self, checkpointer=None, checkpoint_every: int = 0,
             resume: bool = False, extra: Optional[dict] = None,
             superstep_budget: Optional[int] = None):
-        """Run to quiescence. With a `training.checkpoint.Checkpointer` and
+        """Run to quiescence. With a `resilience.checkpoint.Checkpointer` and
         checkpoint_every=N, the BSP loop snapshots (state, inbox, superstep)
         every N supersteps and can restart from the last committed snapshot
         after a failure (BSP makes the cut trivially consistent — paper §4.2's
@@ -1161,29 +1264,15 @@ class GopherEngine:
         Balance's run_with_rebalance) can interleave decisions between
         segments of one logical run and resume exactly where it stopped.
         """
-        if checkpointer is not None and checkpoint_every > 0:
+        ck = checkpointer if checkpoint_every > 0 else None
+        if ck is not None:
             assert not self.tracer.enabled, \
                 "traced runs don't compose with checkpointing yet"
-            return self._run_checkpointed(checkpointer, checkpoint_every,
-                                          resume, extra=extra,
-                                          superstep_budget=superstep_budget)
-        assert superstep_budget is None, \
-            "superstep_budget requires a checkpointed run"
-        gb = (self._graph_block() if self.tracer.enabled
-              else self._gb_for_run(self._graph_block()))
-        if extra:
-            gb = dict(gb)
-            for k, v in extra.items():
-                gb[k] = jnp.asarray(v)
-        if self.tracer.enabled:
-            state, steps, tele = self._run_traced(gb, num_queries=None)
         else:
-            with self._step("dispatch"):
-                state, steps, tele = self._runner(gb_example=gb)(gb)
-        with self._step("download"):
-            state, t = self._finish(state, steps, tele, gb, num_queries=None)
-        self._record_run_metrics(t)
-        return state, t
+            assert superstep_budget is None, \
+                "superstep_budget requires a checkpointed run"
+        return self._run(None, extra, ck, checkpoint_every, resume,
+                         superstep_budget)
 
     def run_queries(self, extra: Optional[dict] = None):
         """Run a query-batched program (``program.num_queries`` = Q) to global
@@ -1200,23 +1289,36 @@ class GopherEngine:
         """
         Q = getattr(self.program, "num_queries", None)
         assert Q is not None, "run_queries requires a query-batched program"
-        gb = dict(self._graph_block() if self.tracer.enabled
-                  else self._gb_for_run(self._graph_block()))
-        for k, v in (extra or {}).items():
-            gb[k] = jnp.asarray(v)
-        if self.tracer.enabled:
-            state, steps, tele = self._run_traced(gb, num_queries=Q)
+        return self._run(Q, extra)
+
+    def _run(self, num_queries, extra, ck=None, every: int = 0,
+             resume: bool = False, budget: Optional[int] = None):
+        """One run: the cached compiled loop, or the host-stepped driver
+        behind an enabled tracer or a checkpointer — closed out by _finish
+        either way."""
+        stepped = ck is not None or self.tracer.enabled
+        gb = (self._graph_block() if stepped
+              else self._gb_for_run(self._graph_block()))
+        if extra:
+            gb = {**gb, **{k: jnp.asarray(v) for k, v in extra.items()}}
+        if stepped:
+            state, steps, tele, exchange = self._run_stepped(
+                gb, num_queries, ck, every, resume, budget)
         else:
+            exchange = self.exchange
             with self._step("dispatch"):
-                state, steps, tele = self._runner(num_queries=Q,
+                state, steps, tele = self._runner(num_queries=num_queries,
                                                   gb_example=gb)(gb)
         with self._step("download"):
-            state, t = self._finish(state, steps, tele, gb, num_queries=Q)
+            state, t = self._finish(state, steps, tele, gb, num_queries,
+                                    exchange)
         self._record_run_metrics(t)
         return state, t
 
-    def _finish(self, state, steps, tele, gb, num_queries):
-        """Close out a run: on the tiered exchange, check the overflow
+    def _finish(self, state, steps, tele, gb, num_queries, exchange: str):
+        """Close out a run of ``exchange`` (the engine's own, or a
+        checkpointed run's replay exchange, see stepped_exchange): on the
+        tiered exchange, check the overflow
         record — a pair whose active slots exceeded its tier width had
         messages TRUNCATED, so the results cannot be trusted. The repair is
         a DENSE FALLBACK RETRY (bit-identical by construction) plus a tier
@@ -1229,7 +1331,7 @@ class GopherEngine:
         only ESCALATES the phases that spilled (each phase's overflow
         record promotes that phase's pairs; the other phases keep their
         geometry)."""
-        if self.exchange == "phased":
+        if exchange == "phased":
             t = self._telemetry(steps, tele, num_queries=num_queries)
             if t.spills:
                 over_k = np.transpose(np.asarray(tele["over"]), (1, 0, 2))
@@ -1241,9 +1343,10 @@ class GopherEngine:
                 self.tier_plan = plan
                 t.escalations = plan.escalations_from(old)
             return jax.tree.map(np.asarray, state), t
-        if self.exchange != "tiered" or "over" not in tele:
+        if exchange != "tiered" or "over" not in tele:
             return (jax.tree.map(np.asarray, state),
-                    self._telemetry(steps, tele, num_queries=num_queries))
+                    self._telemetry(steps, tele, num_queries=num_queries,
+                                    exchange=exchange))
         over = np.asarray(tele["over"])
         spills = int(over.sum())
         if spills == 0:
@@ -1305,25 +1408,57 @@ class GopherEngine:
         m.gauge("engine_partition_imbalance", lab).set(
             obs_skew.imbalance_score(t.local_iters))
 
-    # ---------------- Gopher Scope: traced stepped driver ----------------
-    def _traced_stage_fns(self, num_queries: Optional[int],
-                          phase: Optional[int]):
-        """Jitted per-stage functions for ONE phase (or the run's single
-        exchange): init / sweep / pack / route, each taking the graph block
-        as an argument so the jit cache keys on shapes. On shard_map every
-        stage is its own shard_map'd program — replicated scalars (nsent,
-        wire, dstep) are psum'd INSIDE the stage, per-partition arrays come
-        back as global (P, ...) arrays — so the host driver sees exactly the
-        values the fused loop's stats psum would have produced.
+    # ---------------- the host-stepped driver ----------------
+    def stepped_exchange(self, checkpointed: bool = False) -> str:
+        """The exchange a host-stepped run dispatches — the one rule every
+        stepped caller reads (this engine's driver, Gopher Balance's route
+        replay, Sentinel's stage verification). A traced run steps the
+        engine's own exchange. A checkpointed run — and so every recovery,
+        failover and migration replay — steps the COMPACT staged loop on
+        'megastep'/'tiered'/'phased': the fused megastep route carries no
+        staged (state, inbox) pair to snapshot, and tier-overflow repair
+        and phase segmentation don't span snapshot boundaries. The results
+        are the same (bitwise for idempotent ⊕) per the cross-mode
+        identity tests."""
+        if checkpointed and self.exchange in ("megastep", "tiered", "phased"):
+            return "compact"
+        return self.exchange
 
-        Cached per (num_queries, phase, exchange, tier_plan): repeated
-        traced runs re-enter the same jit entries, and a tier escalation
-        (which changes self.tier_plan) rebuilds the closures."""
-        cache = self.__dict__.setdefault("_traced_cache", {})
-        key = (num_queries, phase, self.exchange, self.tier_plan)
-        fns = cache.get(key)
-        if fns is not None:
-            return fns
+    def stage_fns(self, num_queries: Optional[int], phase: Optional[int],
+                  exchange: str):
+        """The jitted stages a host-stepped run dispatches under
+        ``exchange`` (one ``phase`` of it on 'phased'), each taking the
+        graph block as an argument so the jit cache keys on shapes:
+
+          staged routes  init / sweep / pack / route — the fused loop's
+                         superstep cut at make_exchange_stages' network
+                         boundary (three dispatches per superstep);
+          'megastep'     prep (compose the mailbox gather maps, once per
+                         run) / init (flat state + the prime round's
+                         logical observation) / step (the whole superstep,
+                         ONE dispatch, returning its observation record) /
+                         finish (flat state back to (P, v_max)).
+
+        On shard_map every staged stage is its own shard_map'd program —
+        replicated scalars (nsent, wire) are psum'd INSIDE the stage,
+        per-partition arrays come back as global (P, ...) arrays — so the
+        host driver sees exactly the values the fused loop's stats psum
+        would have produced.
+
+        Cached per (num_queries, phase, exchange, tier plan): repeated runs
+        re-enter the same jit entries, and a tier escalation (which swaps
+        self.tier_plan) rebuilds the closures."""
+        plan = self.tier_plan if exchange in ("tiered", "phased") else None
+        cache = self.__dict__.setdefault("_stage_cache", {})
+        key = (num_queries, phase, exchange, plan)
+        if key not in cache:
+            cache[key] = (self._megastep_stages(num_queries)
+                          if exchange == "megastep"
+                          else self._staged_stages(num_queries, phase,
+                                                   exchange))
+        return cache[key]
+
+    def _staged_stages(self, num_queries, phase, exchange):
         prog = self.program
         Q = num_queries
         axes = ((_VPART_AXIS,) if self.backend == "local"
@@ -1339,7 +1474,7 @@ class GopherEngine:
 
         def pack_fn(gb, state):
             pack, _ = self.make_exchange_stages(gb, num_queries=Q,
-                                                phase=phase)
+                                                phase=phase, exchange=exchange)
             payload, nsent, wire, extras = pack(state)
             if self.backend == "shard_map":
                 s = jax.lax.psum(jnp.stack([nsent, wire]), self.axis_name)
@@ -1348,7 +1483,8 @@ class GopherEngine:
 
         def route_fn(gb, payload):
             _, route = self.make_exchange_stages(gb, num_queries=Q,
-                                                 phase=phase)
+                                                 phase=phase,
+                                                 exchange=exchange)
             inbox, rex = route(payload)
             if self.backend == "shard_map" and "wire" in rex:
                 rex = dict(rex,
@@ -1356,241 +1492,28 @@ class GopherEngine:
             return inbox, rex
 
         if self.backend == "local":
-            fns = dict(init=jax.jit(init_fn), sweep=jax.jit(sweep_fn),
-                       pack=jax.jit(pack_fn), route=jax.jit(route_fn))
-        else:
-            # pytree-prefix specs: parts-sharded unless provably replicated
-            spec, rep = P(self.axis_name), P()
-            fns = dict(
-                init=jax.jit(compat.shard_map(
-                    init_fn, mesh=self.mesh, in_specs=(spec,),
-                    out_specs=spec)),
-                sweep=jax.jit(compat.shard_map(
-                    sweep_fn, mesh=self.mesh,
-                    in_specs=(spec, spec, spec, rep), out_specs=spec)),
-                pack=jax.jit(compat.shard_map(
-                    pack_fn, mesh=self.mesh, in_specs=(spec, spec),
-                    out_specs=(spec, rep, rep, spec))),
-                route=jax.jit(compat.shard_map(
-                    route_fn, mesh=self.mesh, in_specs=(spec, spec),
-                    out_specs=(spec, rep))))
-        cache[key] = fns
-        return fns
+            return dict(init=jax.jit(init_fn), sweep=jax.jit(sweep_fn),
+                        pack=jax.jit(pack_fn), route=jax.jit(route_fn))
+        # pytree-prefix specs: parts-sharded unless provably replicated
+        spec, rep = P(self.axis_name), P()
+        return dict(
+            init=jax.jit(compat.shard_map(
+                init_fn, mesh=self.mesh, in_specs=(spec,), out_specs=spec)),
+            sweep=jax.jit(compat.shard_map(
+                sweep_fn, mesh=self.mesh,
+                in_specs=(spec, spec, spec, rep), out_specs=spec)),
+            pack=jax.jit(compat.shard_map(
+                pack_fn, mesh=self.mesh, in_specs=(spec, spec),
+                out_specs=(spec, rep, rep, spec))),
+            route=jax.jit(compat.shard_map(
+                route_fn, mesh=self.mesh, in_specs=(spec, spec),
+                out_specs=(spec, rep))))
 
-    def _run_traced(self, gb, num_queries: Optional[int] = None):
-        """The host-stepped BSP driver behind an ENABLED tracer: the fused
-        compiled while_loop unrolled into per-superstep jitted stage
-        dispatches, so the tracer can clock every
-        run → phase → superstep → {plan, pack, exchange, sweep, halt-vote}
-        span. Semantics are identical to the fused loop — same stage math
-        (the stages ARE make_exchange's halves), same halt rule, same
-        telemetry layout — the halt vote just becomes a host read of the
-        global changed flags, which is the per-superstep sync a trace needs
-        anyway. The disabled path never comes here (see run())."""
-        tr = self.tracer
-        with tr.profile_ctx():
-            with tr.span("run", exchange=self.exchange,
-                         backend=self.backend,
-                         queries=num_queries or 0) as rs:
-                state, steps, tele = self._traced_loop(gb, num_queries)
-                rs.set(supersteps=steps, wire_slots=int(tele["wire"]))
-        return state, steps, tele
-
-    def _traced_loop(self, gb, num_queries: Optional[int]):
-        tr = self.tracer
-        Q = num_queries
-        mode = self.exchange
-        if mode == "megastep":
-            return self._traced_loop_megastep(gb, Q)
-        phased = mode == "phased"
-        num_parts = self.pg.num_parts
-        max_s = self.max_supersteps
-        if phased:
-            plan: PhasedTierPlan = self.tier_plan
-            K = plan.num_phases
-            bounds = plan.boundaries
-            nlims = [np.asarray(p.limits())
-                     for p in plan.phase_plans()[1:]] + [None]
-        else:
-            K, bounds, nlims = 1, (None,), [None]
-
-        stages = []
-        for k in range(K):
-            # the plan span charges stage construction + first-dispatch
-            # compile to the phase it belongs to (Gopher Hot's plan-pass
-            # attribution)
-            with tr.span("plan", phase=k, exchange=mode,
-                         backend=self.backend):
-                stages.append(self._traced_stage_fns(
-                    Q, k if phased else None))
-
-        with tr.span("init"):
-            state = tr.sync(stages[0]["init"](gb))
-
-        # host-side telemetry accumulators in the exact layout the compiled
-        # loop produces, so _finish/_telemetry are shared verbatim
-        liters = np.zeros(num_parts, np.int64)
-        hist = np.zeros(max_s, np.int64)
-        whist = np.zeros(max_s + 1, np.int64)
-        chist = np.zeros(max_s + 1, np.int64)
-        phist = np.zeros(max_s + 1, np.int64)
-        pairs_acc = (np.zeros((num_parts, K, num_parts), np.int64) if phased
-                     else np.zeros((num_parts, num_parts), np.int64))
-        over_acc = np.zeros_like(pairs_acc)
-        seg_end = np.zeros(K, np.int64)
-        qsteps = np.zeros(Q, np.int64) if Q is not None else None
-        sent = wire_total = dsteps = lsweeps = cwait = 0
-        psec = np.zeros(num_parts, np.float64)
-        part_verts = tuple(int(x) for x in
-                           np.asarray(self.pg.vmask, bool).sum(1))
-        nd = (1 if self.backend == "local"
-              else int(self.mesh.shape[self.axis_name]))
-
-        def fold_pairs(ex, rex, k, rnd):
-            """One round's per-pair telemetry into the host accumulators;
-            returns (wire, Σcounts) as host ints."""
-            nonlocal dsteps, pairs_acc, over_acc
-            wire_i = int(rex["wire"]) if "wire" in rex else None
-            cnt = 0
-            if "pairs" in ex:
-                p = np.asarray(ex["pairs"], np.int64)
-                cnt = int(p.sum())
-                chist[rnd] = cnt
-                if phased:
-                    pairs_acc[:, k] += p
-                else:
-                    pairs_acc += p
-            if "over" in ex:
-                o = np.asarray(ex["over"], np.int64)
-                if phased:
-                    over_acc[:, k] += o
-                else:
-                    over_acc += o
-            if "dstep" in rex:
-                dsteps += int(rex["dstep"])
-            return wire_i, cnt
-
-        with tr.span("prime") as sp:
-            payload, nsent0, wire0, ex0 = stages[0]["pack"](gb, state)
-            inbox, rex = stages[0]["route"](gb, payload)
-            tr.sync(inbox)
-            w, _ = fold_pairs(ex0, rex, 0, 0)
-            wire_i = w if w is not None else int(wire0)
-            sent += int(nsent0)
-            wire_total += wire_i
-            whist[0] = wire_i
-            sp.set(wire=wire_i, nsent=int(nsent0))
-        tr.count("dispatches", 3)
-
-        step = 0
-        done = False
-        for k in range(K):
-            streak = 0
-            with tr.span("phase", index=k,
-                         boundary=(int(bounds[k])
-                                   if phased and k < K - 1 else -1)):
-                while not done and step < max_s:
-                    if phased and k < K - 1 and (
-                            step + 1 >= bounds[k]
-                            or streak >= DEMOTE_STREAK):
-                        break
-                    with tr.span("superstep", step=step) as ss:
-                        t0 = time.perf_counter()
-                        eff = _faults.fire("engine.superstep", step=step,
-                                           backend=self.backend,
-                                           part_verts=part_verts,
-                                           num_devices=nd)
-                        with tr.span("sweep"):
-                            state, changed, li = stages[k]["sweep"](
-                                gb, state, inbox, jnp.int32(step))
-                            tr.sync(changed)
-                        with tr.span("pack"):
-                            payload, nsent, wire, ex = stages[k]["pack"](
-                                gb, state)
-                            tr.sync(payload)
-                        _faults.fire("exchange.route", step=step + 1,
-                                     backend=self.backend)
-                        with tr.span("exchange"):
-                            inbox, rex = stages[k]["route"](gb, payload)
-                            tr.sync(inbox)
-                        with tr.span("halt-vote"):
-                            # the one host sync a trace needs: read the
-                            # global changed flags and decide on the host
-                            # (the fused loop's psum vote, host-side)
-                            ch = np.asarray(changed)
-                            li_np = np.asarray(li, np.int64)
-                            nsent_i = int(nsent)
-                            w, cnt = fold_pairs(ex, rex, k, step + 1)
-                            wire_i = w if w is not None else int(wire)
-                            if Q is None:
-                                nchanged = int(ch.sum())
-                                any_changed = nchanged > 0
-                            else:
-                                changed_q = ch.any(axis=0)
-                                nchanged = int(ch.any(axis=-1).sum())
-                                any_changed = bool(changed_q.any())
-                                qsteps[changed_q] = step + 1
-                        tr.count("dispatches", 3)
-                        dt = time.perf_counter() - t0
-                        stalls = (eff or {}).get("stalls", [])
-                        inj = sum(s for p, s in stalls
-                                  if 0 <= p < num_parts)
-                        psec += max(dt - inj, 0.0) / num_parts
-                        for p, s in stalls:
-                            if 0 <= p < num_parts:
-                                psec[p] += s
-                        liters += li_np
-                        # the fused loop's lockstep and chip-wait counts:
-                        # device d holds the d-th contiguous run of parts
-                        m_d = li_np.reshape(nd, -1).max(axis=1)
-                        lsweeps += int(m_d.max())
-                        cwait += int((m_d.max() - m_d).sum())
-                        hist[step] = nchanged
-                        whist[step + 1] = wire_i
-                        sent += nsent_i
-                        wire_total += wire_i
-                        if phased:
-                            phist[step + 1] = k
-                            if nlims[k] is not None:
-                                viol = int((np.asarray(ex["pairs"])
-                                            > nlims[k]).sum())
-                                streak = streak + 1 if viol == 0 else 0
-                        ss.set(changed=nchanged, wire=wire_i,
-                               nsent=nsent_i)
-                        step += 1
-                        done = not any_changed
-            seg_end[k] = step
-
-        tele = dict(liters=liters, hist=hist, whist=whist,
-                    sent=sent, wire=wire_total, psec=psec, lsweeps=lsweeps,
-                    cwait=cwait)
-        if mode in ("compact", "tiered", "phased"):
-            tele["chist"] = chist
-            tele["pairs"] = pairs_acc
-        if mode in ("tiered", "phased"):
-            tele["over"] = over_acc
-        if phased:
-            tele["phist"] = phist
-            tele["seg_end"] = seg_end
-            tele["dsteps"] = dsteps
-        if Q is not None:
-            tele["qsteps"] = qsteps
-        return state, step, tele
-
-    def _traced_stage_fns_megastep(self, num_queries: Optional[int]):
-        """Jitted stages for the traced megastep driver: prep (compose the
-        mailbox gather maps once per run), init (flat state + the prime
-        round's logical observation), and step — ONE fused dispatch per
-        superstep. The composed-mailbox dict carries static ints
-        (num_parts/v_max/cap/n); they are stripped before crossing the jit
-        boundary and re-injected from the partition scalars inside each
-        stage, so the arrays flow device-to-device without re-composition
-        and the ints never become tracers."""
-        cache = self.__dict__.setdefault("_traced_cache", {})
-        key = (num_queries, "megastep")
-        fns = cache.get(key)
-        if fns is not None:
-            return fns
+    def _megastep_stages(self, num_queries):
+        # the composed-mailbox dict carries static ints (num_parts/v_max/
+        # cap/n): they are stripped before crossing the jit boundary and
+        # re-injected here, so the arrays flow device-to-device without
+        # re-composition and the ints never become tracers
         prog = self.program
         kind = prog.megastep_kind
         Q = num_queries
@@ -1599,21 +1522,16 @@ class GopherEngine:
         statics = dict(num_parts=p_local, v_max=v_max,
                        cap=self.pg.mailbox_cap, n=p_local * v_max)
 
-        def with_statics(cma):
-            return dict(cma, **statics)
-
-        adj = "binned" if kind == "batched_semiring" else "full"
-
         if kind == "pagerank":
             def init_fn(gb, cma):
-                cm = with_statics(cma)
+                cm = dict(cma, **statics)
                 st = jax.vmap(prog.init)(gb)
                 pairs0, nsent0 = mega.round_stats(None, cm)
-                return (st["r"].reshape(-1), jnp.float32(jnp.inf)), \
-                    pairs0, nsent0
+                return ((st["r"].reshape(-1), jnp.float32(jnp.inf)),
+                        dict(pairs=pairs0, nsent=nsent0))
 
             def step_fn(gb, cma, flat, step):
-                cm = with_statics(cma)
+                cm = dict(cma, **statics)
                 r, _ = flat
                 deg = gb["out_degree"].astype(jnp.float32).reshape(-1)
                 telep = (jax.vmap(prog.teleport_fn)(gb).reshape(-1)
@@ -1623,9 +1541,10 @@ class GopherEngine:
                     r, cm, deg, telep, prog.n_global, prog.damping,
                     prog.num_iters, step)
                 pairs, nsent = mega.round_stats(None, cm)
-                chinfo = jnp.broadcast_to(chg, (p_local,))
-                return ((r2, delta), jnp.ones((p_local,), jnp.int32),
-                        pairs, nsent, chinfo, jnp.int32(1))
+                return (r2, delta), dict(
+                    changed=jnp.broadcast_to(chg, (p_local,)),
+                    li=jnp.ones((p_local,), jnp.int32), pairs=pairs,
+                    nsent=nsent, sweeps=jnp.int32(1))
 
             def finish(flat):
                 return {"r": flat[0].reshape(p_local, v_max),
@@ -1639,140 +1558,207 @@ class GopherEngine:
                                          backend=prog.spmv_backend,
                                          interpret=prog.interpret))
             tail = (Q,) if batched else ()
+            names = ("x", "changed_v", "frontier")
 
             def init_fn(gb, cma):
-                cm = with_statics(cma)
+                cm = dict(cma, **statics)
                 st = jax.vmap(prog.init)(gb)
-                flat = tuple(st[k].reshape((-1,) + tail)
-                             for k in ("x", "changed_v", "frontier"))
+                flat = tuple(st[k].reshape((-1,) + tail) for k in names)
                 pairs0, nsent0 = mega.round_stats(flat[1], cm)
-                return flat, pairs0, nsent0
+                return flat, dict(pairs=pairs0, nsent=nsent0)
 
             def step_fn(gb, cma, flat, step):
-                cm = with_statics(cma)
-                x, ch, fr = flat
-                x2, ch2, fl, li, sweeps = mk(x, ch, fr, cm, semiring,
+                cm = dict(cma, **statics)
+                x2, ch2, fl, li, sweeps = mk(*flat, cm, semiring,
                                              unroll=unroll)
                 pairs, nsent = mega.round_stats(ch2, cm)
-                chinfo = jnp.any(
-                    ch2.reshape((p_local, v_max) + tail), axis=1)
-                return (x2, ch2, fl), li, pairs, nsent, chinfo, sweeps
+                return (x2, ch2, fl), dict(
+                    changed=jnp.any(ch2.reshape((p_local, v_max) + tail),
+                                    axis=1),
+                    li=li, pairs=pairs, nsent=nsent, sweeps=sweeps)
 
             def finish(flat):
                 return {k: v.reshape((p_local, v_max) + tail)
-                        for k, v in zip(("x", "changed_v", "frontier"),
-                                        flat)}
+                        for k, v in zip(names, flat)}
 
-        fns = dict(prep=functools.partial(mega.compose_mailbox_arrays,
-                                          adjacency=adj),
-                   init=jax.jit(init_fn),
-                   step=jax.jit(step_fn), finish=finish)
-        cache[key] = fns
-        return fns
+        return dict(prep=functools.partial(
+                        mega.compose_mailbox_arrays,
+                        adjacency=("binned" if kind == "batched_semiring"
+                                   else "full")),
+                    init=jax.jit(init_fn), step=jax.jit(step_fn),
+                    finish=finish)
 
-    def _traced_loop_megastep(self, gb, num_queries: Optional[int]):
-        """Gopher Hot behind an enabled tracer: the fused while_loop
-        unrolled into ONE jitted dispatch per superstep (plus prep + init
-        at the prime), so the trace exhibits the launch-count contraction
-        this route exists for — each superstep span carries a single
-        'megastep' child instead of the staged sweep/pack/exchange trio,
-        and the 'dispatches' counter reads supersteps + 2 instead of
-        3·supersteps + 3. Resident narrow-phase mode is NOT entered here:
-        a trace wants per-superstep spans, and the resident launch hides
-        its rounds inside one kernel."""
+    def restore_cut(self, ck, gb, step: int):
+        """Snapshot ``step`` of checkpointer ``ck`` — the (state, inbox)
+        cut a checkpointed run saved — placed back on this engine's
+        partitions (sharded over the mesh axis on shard_map). Returns
+        (state, inbox, superstep)."""
+        snap_like = {
+            "state": jax.eval_shape(lambda g: jax.vmap(self.program.init)(g),
+                                    gb),
+            "inbox": jax.ShapeDtypeStruct((self.pg.num_parts, self.pg.v_max),
+                                          np.float32),
+        }
+        shardings = None
+        if self.backend == "shard_map":
+            sh = jax.sharding.NamedSharding(self.mesh, P(self.axis_name))
+            shardings = jax.tree.map(lambda _: sh, snap_like)
+        snap, step = ck.restore(snap_like, step=step, shardings=shardings)
+        return snap["state"], snap["inbox"], int(step)
+
+    def _run_stepped(self, gb, num_queries: Optional[int], ck=None,
+                     every: int = 0, resume: bool = False,
+                     budget: Optional[int] = None):
+        """The host-stepped BSP driver: the fused while_loop unrolled into
+        per-superstep dispatches of stage_fns, for every run the compiled
+        loop cannot serve —
+          * an ENABLED tracer: every run → phase → superstep → {sweep,
+            pack, exchange, halt-vote} span is clocked (a single
+            'megastep' child on the fused route; resident narrow-phase
+            mode is not entered, its launch hides its rounds);
+          * a checkpointer: (state, inbox) is snapshotted every ``every``
+            supersteps and at a ``budget`` cut, and ``resume`` restarts
+            from the newest snapshot that passes checksum verification
+            (Checkpointer.latest_good_step) with no prime. Gopher Balance's
+            migrations and Shield's failover run here.
+        Semantics are the fused loop's — the same stage math, the same halt
+        rule (a host read of the global changed flags), the same telemetry
+        layout (_StepTele) — so _finish/_telemetry are shared. Gopher
+        Shield's fault sites fire here, never inside compiled code:
+        ``engine.superstep`` once per superstep, ``exchange.route`` before
+        every staged route, the prime's included.
+
+        Returns (state, supersteps, tele, the exchange stepped)."""
         tr = self.tracer
         Q = num_queries
-        num_parts = self.pg.num_parts
-        max_s = self.max_supersteps
-        with tr.span("plan", phase=0, exchange="megastep",
-                     backend=self.backend):
-            fns = self._traced_stage_fns_megastep(Q)
-
-        with tr.span("init"):
-            cma = fns["prep"](gb)
-            flat, pairs0, nsent0 = fns["init"](gb, cma)
-            tr.sync(pairs0)
-
-        liters = np.zeros(num_parts, np.int64)
-        hist = np.zeros(max_s, np.int64)
-        whist = np.zeros(max_s + 1, np.int64)
-        chist = np.zeros(max_s + 1, np.int64)
-        pairs_acc = np.asarray(pairs0, np.int64)
-        chist[0] = int(pairs_acc.sum())
-        sent = int(nsent0)
-        lsweeps = 0
-        qsteps = np.zeros(Q, np.int64) if Q is not None else None
-        psec = np.zeros(num_parts, np.float64)
+        ex = self.stepped_exchange(checkpointed=ck is not None)
+        fused = ex == "megastep"
+        phased = ex == "phased"
+        K = self.tier_plan.num_phases if phased else 1
+        bounds = self.tier_plan.boundaries if phased else ()
+        # the next phase's caps: the demotion trigger's yardstick
+        nlims = [np.asarray(p.limits())
+                 for p in self.tier_plan.phase_plans()[1:]] if phased else []
+        nlims.append(None)
+        D = (1 if self.backend == "local"
+             else int(self.mesh.shape[self.axis_name]))
         part_verts = tuple(int(x) for x in
                            np.asarray(self.pg.vmask, bool).sum(1))
+        acc = _StepTele(ex, self.pg.num_parts, self.max_supersteps, K, Q, D)
+        stages = []
 
-        with tr.span("prime") as sp:
-            # no routed prime on the fused route: round 0's sends are
-            # delivered by the FIRST megastep dispatch, so the span only
-            # records the logical observation the compact prime would see
-            sp.set(wire=0, nsent=sent)
-        tr.count("dispatches", 2)            # prep + init
+        def route(fns, state, rnd, span):
+            with span("pack"):
+                payload, nsent, wire, pex = fns["pack"](gb, state)
+                tr.sync(payload)
+            _faults.fire("exchange.route", step=rnd, backend=self.backend)
+            with span("exchange"):
+                inbox, rex = fns["route"](gb, payload)
+                tr.sync(inbox)
+            return inbox, {**pex, "nsent": nsent, "wire": wire, **rex}
 
-        step = 0
-        done = False
-        with tr.span("phase", index=0, boundary=-1):
-            while not done and step < max_s:
-                with tr.span("superstep", step=step) as ss:
-                    t0 = time.perf_counter()
-                    eff = _faults.fire("engine.superstep", step=step,
-                                       backend=self.backend,
-                                       part_verts=part_verts,
-                                       num_devices=1)
-                    with tr.span("megastep"):
-                        flat, li, pairs, nsent, chinfo, sweeps = fns["step"](
-                            gb, cma, flat, jnp.int32(step))
-                        tr.sync(li)
-                    with tr.span("halt-vote"):
-                        ch = np.asarray(chinfo)
-                        li_np = np.asarray(li, np.int64)
-                        nsent_i = int(nsent)
-                        p = np.asarray(pairs, np.int64)
-                        if Q is None:
-                            nchanged = int(ch.sum())
-                            any_changed = nchanged > 0
-                        else:
-                            changed_q = ch.any(axis=0)
-                            nchanged = int(ch.any(axis=-1).sum())
-                            any_changed = bool(changed_q.any())
-                            qsteps[changed_q] = step + 1
-                    tr.count("dispatches", 1)   # whole superstep: 1 launch
-                    dt = time.perf_counter() - t0
-                    stalls = (eff or {}).get("stalls", [])
-                    inj = sum(s for p, s in stalls if 0 <= p < num_parts)
-                    psec += max(dt - inj, 0.0) / num_parts
-                    for p, s in stalls:
-                        if 0 <= p < num_parts:
-                            psec[p] += s
-                    liters += li_np
-                    lsweeps += int(sweeps)
-                    hist[step] = nchanged
-                    chist[step + 1] = int(p.sum())
-                    pairs_acc += p
-                    sent += nsent_i
-                    ss.set(changed=nchanged, wire=0, nsent=nsent_i)
-                    step += 1
-                    done = not any_changed
+        def advance(carry, step, fns):
+            """One superstep: (carry, its observation record)."""
+            if fused:
+                with tr.span("megastep"):
+                    carry, obs = fns["step"](gb, cma, carry, jnp.int32(step))
+                    tr.sync(obs["li"])
+                return carry, obs
+            state, inbox = carry
+            with tr.span("sweep"):
+                state, changed, li = fns["sweep"](gb, state, inbox,
+                                                  jnp.int32(step))
+                tr.sync(changed)
+            inbox, obs = route(fns, state, step + 1, tr.span)
+            return (state, inbox), dict(obs, changed=changed, li=li)
 
-        tele = dict(liters=liters, hist=hist, whist=whist, sent=sent,
-                    wire=0, chist=chist, pairs=pairs_acc, psec=psec,
-                    lsweeps=lsweeps)
-        if Q is not None:
-            tele["qsteps"] = qsteps
-        return fns["finish"](flat), step, tele
+        run_span = tr.span("run", exchange=ex, backend=self.backend,
+                           queries=Q or 0)
+        with tr.profile_ctx(), run_span:
+            for k in range(K):
+                # the plan span charges stage construction to its phase
+                with tr.span("plan", phase=k, exchange=ex,
+                             backend=self.backend):
+                    stages.append(self.stage_fns(Q, k if phased else None,
+                                                 ex))
+            good = ck.latest_good_step() if ck is not None and resume else None
+            if good is not None:
+                state, inbox, start = self.restore_cut(ck, gb, good)
+                carry = (state, inbox)
+            else:
+                start, fns = 0, stages[0]
+                with tr.span("init"):
+                    if fused:
+                        cma = fns["prep"](gb)
+                        carry, obs = fns["init"](gb, cma)
+                        tr.sync(obs["pairs"])
+                    else:
+                        state = tr.sync(fns["init"](gb))
+                with tr.span("prime") as sp:
+                    # the fused route routes no prime: the first megastep
+                    # delivers round 0's sends, so the round records only
+                    # the logical observation
+                    if not fused:
+                        inbox, obs = route(fns, state, 0,
+                                           lambda _: contextlib.nullcontext())
+                        carry = (state, inbox)
+                    wire, nsent = acc.fold_round(0, 0, obs)
+                    sp.set(wire=wire, nsent=nsent)
+                tr.count("dispatches", 2 if fused else 3)
+
+            step, done = start, False
+            for k in range(K):
+                streak = 0
+                with tr.span("phase", index=k,
+                             boundary=int(bounds[k]) if k < K - 1 else -1):
+                    while not done and step < self.max_supersteps and (
+                            budget is None or step - start < budget):
+                        if nlims[k] is not None and (
+                                step + 1 >= bounds[k]
+                                or streak >= DEMOTE_STREAK):
+                            break
+                        with tr.span("superstep", step=step) as ss:
+                            t0 = time.perf_counter()
+                            eff = _faults.fire(
+                                "engine.superstep", step=step,
+                                backend=self.backend, part_verts=part_verts,
+                                num_devices=D)
+                            carry, obs = advance(carry, step, stages[k])
+                            with tr.span("halt-vote"):
+                                nchanged, any_changed = acc.fold_step(step,
+                                                                      obs)
+                                wire, nsent = acc.fold_round(step + 1, k,
+                                                             obs)
+                                if nlims[k] is not None:
+                                    viol = (np.asarray(obs["pairs"])
+                                            > nlims[k]).any()
+                                    streak = 0 if viol else streak + 1
+                            tr.count("dispatches", 1 if fused else 3)
+                            acc.charge(time.perf_counter() - t0, eff)
+                            ss.set(changed=nchanged, wire=wire, nsent=nsent)
+                        step += 1
+                        done = not any_changed
+                        if ck is not None and (
+                                done or step >= self.max_supersteps
+                                or (step - start) % every == 0
+                                or step - start == budget):
+                            ck.save({"state": carry[0], "inbox": carry[1]},
+                                    step)
+                acc.seg_end[k] = step
+            # after a resume the exchange rounds cover only THIS process's
+            # supersteps: no prime ran, earlier ones shipped in another
+            tele = acc.out(rounds=step - start + (1 if good is None else 0))
+            run_span.set(supersteps=step, wire_slots=tele["wire"])
+        state = stages[0]["finish"](carry) if fused else carry[0]
+        return state, step, tele, ex
 
     def _telemetry(self, steps, tele, num_queries: Optional[int] = None,
-                   rounds: Optional[int] = None,
                    exchange: Optional[str] = None) -> Telemetry:
         steps = int(steps)
         exchange = exchange or self.exchange
         wire = int(tele["wire"]) if "wire" in tele else 0
-        if rounds is None:
-            rounds = steps + 1                   # supersteps + inbox prime
+        # supersteps + inbox prime; a resumed stepped run reports its own
+        rounds = int(tele.get("rounds", steps + 1))
         D = (1 if self.backend == "local"
              else int(self.mesh.shape[self.axis_name]))
         phased = exchange == "phased" and "phist" in tele
@@ -1934,149 +1920,6 @@ class GopherEngine:
             _RUNNER_CACHE[key] = cached
         self._runner_memo[mkey] = (tier_plan, cached)
         return cached
-
-    def _run_checkpointed(self, ck, every: int, resume: bool,
-                          extra: Optional[dict] = None,
-                          superstep_budget: Optional[int] = None):
-        """Checkpointable BSP: a host-stepped driver over the STAGED stage
-        functions (Gopher Scope's init/sweep/pack/route jits — bit-identical
-        to the fused loops), snapshotting (state, inbox, superstep) every
-        `every` supersteps on BOTH backends. Tiered/phased/megastep configs
-        drop to the compact staged loop — same results (bitwise for
-        idempotent ⊕) per the cross-mode identity tests: tier overflow
-        repair and phase segmentation don't span snapshot boundaries, and
-        the fused megastep route carries no staged (state, inbox) pair to
-        snapshot. Reuses the engine's cached graph block — a checkpointed
-        run must not build a second device copy — and carries the same
-        telemetry counters as a normal run (after a resume, counters cover
-        the current process's supersteps; the hist slots before the
-        restored step are zero).
-
-        Restore goes through the newest snapshot that passes checksum
-        verification (Checkpointer.latest_good_step): a corrupt/truncated
-        snapshot automatically falls back to the previous good one. Gopher
-        Shield fault sites `engine.superstep` / `exchange.route` fire in
-        this host loop — never inside compiled code."""
-        if self.exchange in ("megastep", "tiered", "phased"):
-            prev = self.exchange
-            self.exchange = "compact"
-            try:
-                return self._run_checkpointed(
-                    ck, every, resume, extra,
-                    superstep_budget=superstep_budget)
-            finally:
-                self.exchange = prev
-        gb = self._graph_block()
-        if extra:
-            gb = dict(gb)
-            for k, v in extra.items():
-                gb[k] = jnp.asarray(v)
-        prog = self.program
-        num_parts, v_max = self.pg.num_parts, self.pg.v_max
-        max_s = self.max_supersteps
-        fns = self._traced_stage_fns(None, None)
-
-        # host telemetry accumulators in the fused loop's exact layout
-        liters = np.zeros(num_parts, np.int64)
-        hist = np.zeros(max_s, np.int64)
-        whist = np.zeros(max_s + 1, np.int64)
-        chist = np.zeros(max_s + 1, np.int64)
-        pairs_acc = np.zeros((num_parts, num_parts), np.int64)
-        sent = wire_total = 0
-        # Gopher Balance time channel: injected stalls land on their target
-        # partition, the rest of each superstep's wall time spreads evenly
-        psec = np.zeros(num_parts, np.float64)
-        part_verts = tuple(int(x) for x in
-                           np.asarray(self.pg.vmask, bool).sum(1))
-        D = (1 if self.backend == "local"
-             else int(self.mesh.shape[self.axis_name]))
-
-        good = None
-        if resume:
-            good = (ck.latest_good_step() if hasattr(ck, "latest_good_step")
-                    else ck.latest_step())
-        if good is not None:
-            snap_like = {
-                "state": jax.eval_shape(lambda g: jax.vmap(prog.init)(g), gb),
-                "inbox": jax.ShapeDtypeStruct((num_parts, v_max),
-                                              np.float32),
-            }
-            shardings = None
-            if self.backend == "shard_map":
-                sh = jax.sharding.NamedSharding(self.mesh, P(self.axis_name))
-                shardings = jax.tree.map(lambda _: sh, snap_like)
-            snap, step = ck.restore(snap_like, step=good,
-                                    shardings=shardings)
-            state, inbox = snap["state"], snap["inbox"]
-            step = int(step)
-            primed = False
-        else:
-            state = fns["init"](gb)
-            payload, nsent0, wire0, ex0 = fns["pack"](gb, state)
-            _faults.fire("exchange.route", step=0, backend=self.backend)
-            inbox, rex0 = fns["route"](gb, payload)
-            wire_i = int(rex0["wire"]) if "wire" in rex0 else int(wire0)
-            sent += int(nsent0)
-            wire_total += wire_i
-            whist[0] = wire_i                    # round 0 = the prime
-            if "pairs" in ex0:
-                p0 = np.asarray(ex0["pairs"], np.int64)
-                pairs_acc += p0
-                chist[0] = int(p0.sum())
-            step = 0
-            primed = True
-
-        start = step
-        budget = superstep_budget
-        done = False
-        while not done and step < max_s and (budget is None
-                                             or step - start < budget):
-            t0 = time.perf_counter()
-            eff = _faults.fire("engine.superstep", step=step,
-                               backend=self.backend,
-                               part_verts=part_verts, num_devices=D)
-            state, changed, li = fns["sweep"](gb, state, inbox,
-                                              jnp.int32(step))
-            payload, nsent, wire, ex = fns["pack"](gb, state)
-            _faults.fire("exchange.route", step=step + 1,
-                         backend=self.backend)
-            inbox, rex = fns["route"](gb, payload)
-            ch = np.asarray(changed)
-            nchanged = int(ch.sum())
-            wire_i = int(rex["wire"]) if "wire" in rex else int(wire)
-            dt = time.perf_counter() - t0
-            stalls = (eff or {}).get("stalls", [])
-            inj = sum(s for p, s in stalls if 0 <= p < num_parts)
-            psec += max(dt - inj, 0.0) / num_parts
-            for p, s in stalls:
-                if 0 <= p < num_parts:
-                    psec[p] += s
-            liters += np.asarray(li, np.int64)
-            hist[step] = nchanged
-            whist[step + 1] = wire_i
-            sent += int(nsent)
-            wire_total += wire_i
-            if "pairs" in ex:
-                p = np.asarray(ex["pairs"], np.int64)
-                pairs_acc += p
-                chist[step + 1] = int(p.sum())
-            step += 1
-            done = nchanged == 0
-            cut = budget is not None and step - start >= budget
-            if done or cut or (step - start) % every == 0 or step >= max_s:
-                ck.save({"state": state, "inbox": inbox}, step)
-        # after a resume the wire counters cover only THIS process's
-        # exchanges, so the byte model must count the same rounds (no prime
-        # ran, and pre-resume supersteps shipped in the previous process)
-        rounds = step - start + (1 if primed else 0)
-        tele = dict(liters=liters, hist=hist, whist=whist, sent=sent,
-                    wire=wire_total, psec=psec)
-        if self.exchange == "compact":
-            tele["chist"] = chist
-            tele["pairs"] = pairs_acc
-        t = self._telemetry(step, tele, rounds=rounds)
-        self._record_run_metrics(t)
-        return jax.tree.map(np.asarray, state), t
 
     def _sharded_fn(self, num_queries: Optional[int] = None, gb_example=None):
         spec = P(self.axis_name)

@@ -106,7 +106,7 @@ def scenario_device_loss(args):
     from repro.core import (GopherEngine, PhasedTierPlan, host_graph_block)
     from repro.core import compat
     from repro.resilience import faults, run_with_failover
-    from repro.training.checkpoint import Checkpointer
+    from repro.resilience.checkpoint import Checkpointer
     D = args.devices
     if jax.device_count() < D:
         return {"ok": False,
@@ -149,7 +149,7 @@ def scenario_device_loss(args):
 def scenario_corrupt_snapshot(args):
     """Bit-flip the newest snapshot; resume must fall back one step."""
     from repro.core import GopherEngine
-    from repro.training.checkpoint import Checkpointer
+    from repro.resilience.checkpoint import Checkpointer
     _, pg = _graph(args)
     prog = _program("cc", pg)
     ref, _ = GopherEngine(pg, prog, backend="local", exchange="dense").run()
@@ -248,7 +248,7 @@ def scenario_straggler(args):
     """Injected superstep stalls: completion + bit-identical results."""
     from repro.core import GopherEngine
     from repro.resilience import faults
-    from repro.training.checkpoint import Checkpointer
+    from repro.resilience.checkpoint import Checkpointer
     _, pg = _graph(args)
     prog = _program("cc", pg)
     ref, _ = GopherEngine(pg, prog, backend="local", exchange="dense").run()
@@ -311,7 +311,7 @@ def scenario_skew_heal(args):
     from repro.resilience import faults
     from repro.resilience.balance import (BalancePolicy, run_with_rebalance,
                                           to_global)
-    from repro.training.checkpoint import Checkpointer
+    from repro.resilience.checkpoint import Checkpointer
     _, pg = _skew_graph(args)
     part0 = np.asarray(pg.part_of).copy()
     algos = ("cc",) if args.quick else ("cc", "pagerank")

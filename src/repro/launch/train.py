@@ -20,7 +20,7 @@ from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.models.sharding import set_rules
 from repro.training import optimizer as O
-from repro.training.checkpoint import Checkpointer
+from repro.resilience.checkpoint import Checkpointer
 from repro.training.data import DataCfg, make_dataset
 from repro.training.shardspec import batch_pspecs, named, state_pspecs
 from repro.training.train_step import make_train_step

@@ -8,7 +8,7 @@ migration is the vertex-centric world's standard remedy). This module closes
 the telemetry → decision → migration → verify loop around signals that
 already exist:
 
-  telemetry   ``Telemetry.part_seconds`` (the host-stepped drivers' wall
+  telemetry   ``Telemetry.part_seconds`` (the host-stepped driver's wall
               clock, where injected straggler stalls land) + the iteration
               channel, scored by ``obs.skew`` / ``SkewTracker``;
   decision    ``launch/elastic.rebalance_hint`` (threshold + hysteresis
@@ -438,27 +438,14 @@ def migrate_and_resume(engine, checkpointer, plan: MigrationPlan,
     # re-home the snapshot: restore → remap state → re-home or recompute the
     # inbox (see below) → re-commit at the same step
     ck = checkpointer
-    good = (ck.latest_good_step() if hasattr(ck, "latest_good_step")
-            else ck.latest_step())
+    good = ck.latest_good_step()
     assert good is not None, "migration needs a committed snapshot to re-home"
     P_, v_max = pg.num_parts, pg.v_max
     gb = ne._graph_block()
     if extra:
-        gb = dict(gb)
-        for k, v in extra.items():
-            gb[k] = jnp.asarray(v)
-    snap_like = {
-        "state": jax.eval_shape(lambda g: jax.vmap(ne.program.init)(g), gb),
-        "inbox": jax.ShapeDtypeStruct((P_, v_max), np.float32),
-    }
-    shardings = None
-    if ne.backend == "shard_map":
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as _P
-        sh = NamedSharding(ne.mesh, _P(ne.axis_name))
-        shardings = jax.tree.map(lambda _: sh, snap_like)
-    snap, step = ck.restore(snap_like, step=good, shardings=shardings)
-    state = remap_state(snap["state"], res, P_, v_max)
+        gb = {**gb, **{k: jnp.asarray(v) for k, v in extra.items()}}
+    state, saved_inbox, step = ne.restore_cut(ck, gb, good)
+    state = remap_state(state, res, P_, v_max)
     # The saved inbox carries the cut's PENDING DELIVERIES — messages whose
     # senders changed in the last superstep and whose receivers only learn
     # of them from the next mailbox. An edge the migration converted from
@@ -474,19 +461,15 @@ def migrate_and_resume(engine, checkpointer, plan: MigrationPlan,
     #     recomputing route(pack(state)) on the new topology is complete
     #     AND exact.
     if getattr(ne.program, "combine", None) in ("min", "max"):
-        inbox = np.asarray(snap["inbox"]).copy()
+        inbox = np.asarray(saved_inbox).copy()
         inbox[res.plan.dst, res.new_slots] = \
             inbox[res.plan.src, res.old_slots]
     else:
-        prev = ne.exchange
-        if prev in ("megastep", "tiered", "phased"):
-            ne.exchange = "compact"      # the checkpointed driver's own drop
-        try:
-            fns = ne._traced_stage_fns(None, None)
-            payload = fns["pack"](gb, jax.tree.map(jnp.asarray, state))[0]
-            inbox = fns["route"](gb, payload)[0]
-        finally:
-            ne.exchange = prev
+        # the exchange the resumed checkpointed run will step
+        fns = ne.stage_fns(None, None,
+                           ne.stepped_exchange(checkpointed=True))
+        payload = fns["pack"](gb, jax.tree.map(jnp.asarray, state))[0]
+        inbox = fns["route"](gb, payload)[0]
     ck.save({"state": jax.tree.map(np.asarray, state),
              "inbox": np.asarray(inbox)}, int(step))
     ne.metrics.counter("rebalance_migrations_total",

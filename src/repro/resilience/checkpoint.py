@@ -7,9 +7,10 @@ Layout:
 Each leaf is stored as the set of its addressable shards (device index ->
 array block). On restore, blocks are reassembled into the full array and
 re-placed under the *target* mesh's shardings — which may have a different
-shape than the mesh that saved it (elastic restart after node loss). BSP
-checkpoints of the graph engine reuse the same functions (their state is just
-a pytree).
+shape than the mesh that saved it (elastic restart after node loss). The
+graph engine's stepped BSP driver snapshots its (state, inbox) cut through it
+(``GopherEngine.run(checkpointer=...)``); the training launcher saves its
+train state the same way (both are just pytrees).
 """
 from __future__ import annotations
 

@@ -1,13 +1,13 @@
 """Gopher Shield — deterministic fault injection.
 
 A :class:`FaultPlan` is a seeded, replayable schedule of faults fired at
-NAMED SITES — host-side hook points the engine's stepped drivers, the block
-patcher, and the serving loop already pass through:
+NAMED SITES — host-side hook points the engine's host-stepped driver, the
+block patcher, and the serving loop already pass through:
 
     engine.superstep    once per superstep of a stepped (checkpointed or
-                        traced) BSP driver, before the sweep dispatch
-    exchange.route      once per mailbox routing round, before the route
-                        dispatch
+                        traced) BSP run, before the superstep's dispatch
+    exchange.route      once per staged mailbox routing round, the inbox
+                        prime's included, before the route dispatch
     blocks.patch        on entry to core.blocks.patch_host_block
     svc.apply_delta     on entry of a GraphQueryService delta-apply attempt
     svc.query           on entry of a GraphQueryService batch run attempt
@@ -89,8 +89,8 @@ def _straggler_stalls(spec: "FaultSpec", ctx: dict) -> list:
     """Sleep out one straggler firing and return its [(part, seconds)]
     attribution. Targeted specs (payload ``part``/``device``) stall
     ``delay_s`` per live vertex of each targeted partition — read from the
-    ``part_verts`` tuple in the fire context (the engine's stepped drivers
-    pass it; ``num_devices`` maps a device target onto its contiguous
+    ``part_verts`` tuple in the fire context (the engine's stepped driver
+    passes it; ``num_devices`` maps a device target onto its contiguous
     partition rows, the same P//D tiling failover uses). Untargeted specs,
     or sites that don't carry ``part_verts``, keep the flat legacy sleep
     attributed to no partition (part -1)."""

@@ -48,7 +48,7 @@ def recover(engine, checkpointer, every: int = 1, extra: Optional[dict] = None
             ) -> Tuple[object, object]:
     """One restore-and-continue: resume from the newest GOOD snapshot and
     run to quiescence. Returns (state, telemetry) — bit-identical to what
-    the interrupted run would have produced (the checkpointed driver's
+    the interrupted run would have produced (the host-stepped driver's
     staged stages are the same jits either way)."""
     return engine.run(checkpointer=checkpointer, checkpoint_every=every,
                       resume=True, extra=extra)
@@ -67,7 +67,7 @@ def run_with_recovery(engine, checkpointer, every: int = 1,
     The first attempt starts cold (or resumes, if the checkpoint directory
     already holds committed snapshots and the fault fires before any new
     save — latest_good_step of an empty directory is None, which the
-    checkpointed driver treats as a cold start). Each restart resumes from
+    host-stepped driver treats as a cold start). Each restart resumes from
     the newest checksum-verified snapshot. Returns
     ``(state, telemetry, RecoveryReport)``; raises :class:`RecoveryExhausted`
     when ``max_restarts`` is spent. ``DeviceLossFault`` is deliberately NOT

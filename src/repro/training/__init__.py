@@ -1,1 +1,2 @@
-"""Training substrate: optimizer, steps, data, checkpointing, sharding specs."""
+"""Training substrate: optimizer, steps, data, sharding specs (checkpoints:
+repro.resilience.checkpoint)."""

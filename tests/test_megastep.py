@@ -162,7 +162,7 @@ def test_incremental_resume_rides_fused_route(road):
 
 
 def test_checkpointed_run_falls_back_to_staged(road, tmp_path):
-    from repro.training.checkpoint import Checkpointer
+    from repro.resilience.checkpoint import Checkpointer
     _, pg = road
     prog = _programs(pg)["sssp"]
     s_ref, t_ref = GopherEngine(pg, prog, exchange="dense").run()
@@ -172,6 +172,25 @@ def test_checkpointed_run_falls_back_to_staged(road, tmp_path):
     assert np.array_equal(np.asarray(s["x"]), np.asarray(s_ref["x"]))
     assert t.supersteps == t_ref.supersteps
     assert eng.exchange == "megastep"   # the fallback must not stick
+    # ... nor in the modules that verify or replay the compact stages
+    from repro.analysis import validate_stage_fns
+    validate_stage_fns(eng)
+    assert eng.exchange == "megastep"
+    # Balance's route replay (⊕ = sum re-routes the restored state): a
+    # strip layout whose partition 0 holds two sub-graphs, 1 and 2 slack
+    from repro.resilience import migrate_and_resume, plan_migration
+    g = road_grid(6, 12, drop_frac=0.0, seed=0)
+    strip = np.asarray([0, 1, 2, 0, 3, 3], np.int32)[
+        (np.arange(72) % 12) // 2]
+    spg = partition_graph(g, strip, 4)
+    pr = GopherEngine(spg, PageRankProgram(n_global=spg.n_global,
+                                           num_iters=6))
+    ck = Checkpointer(str(tmp_path / "pr"))
+    pr.run(checkpointer=ck, checkpoint_every=1, superstep_budget=2)
+    ne, _, at = migrate_and_resume(pr, ck, plan_migration(spg, src=0,
+                                                          budget=12))
+    assert at == 2
+    assert pr.exchange == ne.exchange == "megastep"
 
 
 # ---------------- resident narrow-phase mode ----------------

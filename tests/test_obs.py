@@ -13,8 +13,9 @@ Contract under test:
     annotations are monotone (the megastep route ships nothing — its wire
     accounting is all zero while the logical counts persist);
   - the traced stepped driver is bit-identical to the fused compiled loop
-    (states AND telemetry), on every discipline — tracing observes, never
-    perturbs;
+    (states AND telemetry), on every discipline, for CC, SSSP and
+    query-batched SSSP (PageRank's float sums: allclose) — tracing
+    observes, never perturbs;
   - the engine, tier planner and serving loop feed the registry, and
     GraphQueryService.stats() reports latency percentiles, cache hit rate
     and live per-partition imbalance.
@@ -26,7 +27,8 @@ import sys
 import numpy as np
 import pytest
 
-from repro.core import GopherEngine, PhasedTierPlan, SemiringProgram, TierPlan
+from repro.core import (GopherEngine, PageRankProgram, PhasedTierPlan,
+                        SemiringProgram, TierPlan)
 from repro.core import init_max_vertex, make_sssp_init
 from repro.gofs import bfs_grow_partition, road_grid
 from repro.gofs.formats import partition_graph
@@ -46,6 +48,8 @@ def road():
 def _prog(pg, algo="cc"):
     if algo == "cc":
         return SemiringProgram(semiring="max_first", init_fn=init_max_vertex)
+    if algo == "pagerank":
+        return PageRankProgram(n_global=pg.n_global, num_iters=12)
     return SemiringProgram(
         semiring="min_plus",
         init_fn=make_sssp_init(int(pg.part_of[0]), int(pg.local_of[0])))
@@ -196,17 +200,46 @@ def test_telemetry_round_invariants(road, exchange, algo):
 
 # ---------------- traced == untraced ----------------
 
-@pytest.mark.parametrize("exchange", MODES)
-def test_traced_run_bit_identical(road, exchange):
-    g, pg = road
-    prog = _prog(pg, "sssp")
+# sssp keeps its original ids; the query-batched case rides run_queries on
+# the batched megastep route
+_TRACED_CASES = (
+    [pytest.param(ex, "sssp", id=ex) for ex in MODES]
+    + [pytest.param(ex, algo, id=f"{ex}-{algo}")
+       for algo in ("cc", "pagerank") for ex in MODES]
+    + [pytest.param("megastep", "sssp-queries", id="megastep-queries")])
+
+
+def _traced_pair(pg, exchange, algo, tracer):
+    """One run of ``algo`` on ``exchange``: (state leaf, Telemetry)."""
     plan = _plan(pg, exchange)
-    s0, t0 = GopherEngine(pg, prog, exchange=exchange, tier_plan=plan).run()
+    if algo != "sssp-queries":
+        prog = _prog(pg, algo)
+        s, t = GopherEngine(pg, prog, exchange=exchange, tier_plan=plan,
+                            tracer=tracer).run()
+        return np.asarray(s["r" if algo == "pagerank" else "x"]), t
+    from repro.serving.batched import (QUERY_INIT_KEY,
+                                       BatchedSemiringProgram,
+                                       sssp_query_init)
+    prog = BatchedSemiringProgram(semiring="min_plus", num_queries=3)
+    extra = {QUERY_INIT_KEY: sssp_query_init(pg, [0, 7, 19])}
+    s, t = GopherEngine(pg, prog, exchange=exchange, tier_plan=plan,
+                        tracer=tracer).run_queries(extra=extra)
+    return np.asarray(s["x"]), t
+
+
+@pytest.mark.parametrize("exchange,algo", _TRACED_CASES)
+def test_traced_run_bit_identical(road, exchange, algo):
+    g, pg = road
+    x0, t0 = _traced_pair(pg, exchange, algo, None)
     tracer = Tracer(enabled=True)
-    s1, t1 = GopherEngine(pg, prog, exchange=exchange, tier_plan=plan,
-                          tracer=tracer).run()
-    np.testing.assert_array_equal(np.asarray(s0["x"]), np.asarray(s1["x"]))
+    x1, t1 = _traced_pair(pg, exchange, algo, tracer)
+    if algo == "pagerank":       # ⊕ = sum: stage-split float sums
+        np.testing.assert_allclose(x0, x1, rtol=1e-5, atol=1e-7)
+    else:
+        np.testing.assert_array_equal(x0, x1)
     assert t0.supersteps == t1.supersteps
+    np.testing.assert_array_equal(t0.changed_hist, t1.changed_hist)
+    np.testing.assert_array_equal(t0.query_supersteps, t1.query_supersteps)
     assert t0.wire_slots == t1.wire_slots
     np.testing.assert_array_equal(t0.wire_hist, t1.wire_hist)
     np.testing.assert_array_equal(t0.local_iters, t1.local_iters)

@@ -52,7 +52,7 @@ from repro.resilience import (  # noqa: E402
 from repro.resilience.degrade import CircuitBreaker, backoff_delays  # noqa: E402
 from repro.resilience.failover import _largest_divisor_at_most  # noqa: E402
 from repro.serving.service import GraphQueryService  # noqa: E402
-from repro.training.checkpoint import Checkpointer  # noqa: E402
+from repro.resilience.checkpoint import Checkpointer  # noqa: E402
 
 
 def _pg(n=100, deg=4.0, parts=8, seed=3):
@@ -246,7 +246,7 @@ from repro.gofs.formats import partition_graph
 from repro.gofs.generators import random_graph
 from repro.gofs.partition import bfs_grow_partition
 from repro.resilience import faults, run_with_failover
-from repro.training.checkpoint import Checkpointer
+from repro.resilience.checkpoint import Checkpointer
 g = random_graph(120, avg_degree=4.0, seed=3, weighted=True)
 pg = partition_graph(g, bfs_grow_partition(g, 8, seed=0), 8)
 prog = SemiringProgram(semiring="min_plus",

@@ -180,7 +180,7 @@ def test_bsp_checkpoint_restart(tmp_path):
     """Fault tolerance: kill the BSP run mid-way, restart from the last
     committed superstep snapshot, converge to the identical answer."""
     from repro.core import GopherEngine, SemiringProgram, init_max_vertex
-    from repro.training.checkpoint import Checkpointer
+    from repro.resilience.checkpoint import Checkpointer
     g = road_grid(16, 16, drop_frac=0.05, seed=11)
     pg = partition_graph(g, bfs_grow_partition(g, 4, seed=0), 4)
 
@@ -204,9 +204,10 @@ def test_bsp_checkpoint_restart(tmp_path):
 def test_checkpointed_run_telemetry_and_block_reuse(tmp_path):
     """Regression: checkpointed runs must reuse the engine's cached device
     graph block (not rebuild a second copy) and report REAL telemetry —
-    message counts and per-superstep changed history, like normal runs."""
+    message counts, per-superstep changed history, and the lockstep and
+    chip-wait sweeps of the compact loop they step, like normal runs."""
     from repro.core import GopherEngine, SemiringProgram, init_max_vertex
-    from repro.training.checkpoint import Checkpointer
+    from repro.resilience.checkpoint import Checkpointer
     g = road_grid(14, 14, drop_frac=0.05, seed=12)
     pg = partition_graph(g, bfs_grow_partition(g, 4, seed=0), 4)
     prog = SemiringProgram(semiring="max_first", init_fn=init_max_vertex)
@@ -222,3 +223,6 @@ def test_checkpointed_run_telemetry_and_block_reuse(tmp_path):
     assert tele.messages_sent == ref_tele.messages_sent >= 0
     assert np.array_equal(tele.changed_hist, ref_tele.changed_hist)
     assert np.array_equal(tele.local_iters, ref_tele.local_iters)
+    _, compact = GopherEngine(pg, prog, exchange="compact").run()
+    assert tele.lockstep_sweeps == compact.lockstep_sweeps is not None
+    assert tele.chip_wait_sweeps == compact.chip_wait_sweeps is not None

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 from repro.configs import ARCHS
 from repro.models import init_params
 from repro.training import optimizer as O
-from repro.training.checkpoint import Checkpointer
+from repro.resilience.checkpoint import Checkpointer
 from repro.training.data import DataCfg, SyntheticLM, make_dataset
 from repro.training.shardspec import param_pspecs
 from repro.training.train_step import IGNORE, cross_entropy, make_train_step
